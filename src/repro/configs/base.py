@@ -45,7 +45,7 @@ class ModelConfig:
     rope_theta: float = 1e4
     attn_chunk: int = 1024      # query-chunk size of the flash-style scan
     attn_impl: str = "jnp"      # "jnp" (shardable reference) | "pallas"
-                                # (kernels/flash_attention, interpret on CPU)
+                                # (kernels/flash_attention, forward only)
     # --- modality frontend stub (audio/vlm): number of precomputed
     # frame/patch embeddings prepended to the token sequence.
     frontend: str = "none"      # none | audio | vision

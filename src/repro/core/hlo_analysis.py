@@ -40,12 +40,8 @@ _SKIP_BYTES = {"parameter", "constant", "get-tuple-element", "tuple",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Version-compat wrapper: ``Compiled.cost_analysis()`` returns a dict
-    on current jax but a per-partition list of dicts on older releases."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` (a dict), empty when XLA reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _type_bytes(type_str: str) -> int:

@@ -49,7 +49,7 @@ from dataclasses import dataclass, field
 import time
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import numpy as np
@@ -347,20 +347,20 @@ class PipelineRunner:
         self._fwd[u] = jax.jit(shard_map(
             B["fwd"], mesh=mesh,
             in_specs=(B["p_specs"], B["c_specs"], B["mb_specs"]),
-            out_specs=B["fwd_out_specs"], check_rep=False))
+            out_specs=B["fwd_out_specs"], check_vma=False))
         in_specs = (B["p_specs"], B["c_specs"], B["mb_specs"],
                     B["dout_specs"])
         if self.has_w:
             self._bwd_act[u] = jax.jit(shard_map(
                 B["bwd_act"], mesh=mesh, in_specs=in_specs,
-                out_specs=B["c_specs"], check_rep=False))
+                out_specs=B["c_specs"], check_vma=False))
             self._bwd_wgt[u] = jax.jit(shard_map(
                 B["bwd_wgt"], mesh=mesh, in_specs=in_specs,
-                out_specs=B["p_specs"], check_rep=False))
+                out_specs=B["p_specs"], check_vma=False))
         else:
             self._bwd[u] = jax.jit(shard_map(
                 B["bwd"], mesh=mesh, in_specs=in_specs,
-                out_specs=(B["p_specs"], B["c_specs"]), check_rep=False))
+                out_specs=(B["p_specs"], B["c_specs"]), check_vma=False))
 
     # ------------------------------------------------------------- step
     def step(self, params_list, batch, *, record: bool = False) -> tuple:
@@ -691,19 +691,19 @@ class CompiledPipelineRunner(PipelineRunner):
         p_specs = B["p_specs"]
         self._fscan[u] = jax.jit(shard_map(
             f_scan, mesh=mesh, in_specs=(p_specs, cs_specs, mbs_specs),
-            out_specs=outs_specs, check_rep=False))
+            out_specs=outs_specs, check_vma=False))
         in_specs = (p_specs, cs_specs, mbs_specs, douts_specs)
         if self.has_w:
             self._bscan_act[u] = jax.jit(shard_map(
                 b_scan_act, mesh=mesh, in_specs=in_specs,
-                out_specs=cs_specs, check_rep=False))
+                out_specs=cs_specs, check_vma=False))
             self._bscan_wgt[u] = jax.jit(shard_map(
                 b_scan_wgt, mesh=mesh, in_specs=in_specs,
-                out_specs=p_specs, check_rep=False))
+                out_specs=p_specs, check_vma=False))
         else:
             self._bscan[u] = jax.jit(shard_map(
                 b_scan, mesh=mesh, in_specs=in_specs,
-                out_specs=(p_specs, cs_specs), check_rep=False))
+                out_specs=(p_specs, cs_specs), check_vma=False))
 
     # ------------------------------------------------------------- step
     def step(self, params_list, batch, *, record: bool = False) -> tuple:

@@ -18,6 +18,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -66,9 +68,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     static_argnames=("causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q/k/v: (B, H, S, hd) -> (B, H, S, hd). MHA-level (GQA expansion in
-    ops.py). interpret=True validates on CPU; False targets real TPUs."""
+    ops.py). ``interpret=None`` compiles Mosaic on a TPU backend and
+    interprets the kernel body anywhere else (see ``kernels.resolve_interpret``)."""
     B, H, S, hd = q.shape
     bq = min(block_q, S)
     bk = min(block_k, S)
@@ -80,7 +83,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     kernel = functools.partial(
         _flash_kernel, bq=bq, bk=bk, nk=nk, causal=causal, window=window)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
@@ -95,6 +98,22 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # running max
             pltpu.VMEM((bq,), jnp.float32),      # running denominator
         ],
-        interpret=interpret,
-    )(qf, kf, vf)
-    return out.reshape(B, H, S, hd)
+        interpret=resolve_interpret(interpret),
+    )
+    return _forward_only(call)(qf, kf, vf).reshape(B, H, S, hd)
+
+
+def _forward_only(call):
+    """The kernel has no backward pass: differentiating it raises a plain
+    error instead of failing deep inside Pallas' own autodiff."""
+    @jax.custom_vjp
+    def f(*xs):
+        return call(*xs)
+
+    def bwd(_res, _g):
+        raise NotImplementedError(
+            "the Pallas flash_attention kernel has no backward pass; "
+            "train with attn_impl='jnp'")
+
+    f.defvjp(lambda *xs: (call(*xs), None), bwd)
+    return f

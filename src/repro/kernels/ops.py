@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These are the drop-in entry points the model layers can route through
-(GQA head expansion, D-skip/gating composition, interpret-mode selection).
-On this CPU container ``interpret=True`` executes the kernel bodies in
-Python for correctness validation; on a real TPU pass interpret=False.
+(GQA head expansion, D-skip/gating composition). ``interpret=None`` lets
+the kernels decide from the backend: compiled Mosaic on a TPU, the kernel
+body interpreted elsewhere (CPU tests).
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ from repro.kernels.ssd_scan import ssd_scan
 
 def gqa_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """q: (B, S, H, hd); k/v: (B, S, KV, hd) -> (B, S, H, hd).
     Expands GQA KV heads and routes through the flash kernel."""
     B, S, H, hd = q.shape
@@ -31,7 +31,7 @@ def gqa_flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def mamba_ssd(x, dt, A, B, C, D_skip=None, *, chunk: int = 128,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """SSD scan + optional D-skip. Shapes as in kernels.ssd_scan."""
     y = ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=interpret)
     if D_skip is not None:

@@ -6,18 +6,11 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit/auto axis types on meshes
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,10 +24,11 @@ def make_mesh(shape, axes):
     return _make_mesh(tuple(shape), tuple(axes))
 
 
-def make_host_mesh():
-    """Whatever devices exist locally, as a ("data",) mesh (tests/smoke)."""
-    n = len(jax.devices())
-    return _make_mesh((n,), ("data",))
+def make_host_mesh(devices=None):
+    """``devices`` (default: every local device) as a ("data",) mesh."""
+    devices = jax.devices() if devices is None else list(devices)
+    return jax.sharding.Mesh(devices, ("data",),
+                             axis_types=(AxisType.Auto,))
 
 
 def stage_device_sets(stage_plan, devices=None) -> list:

@@ -23,6 +23,7 @@ from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.configs.shapes import InputShape
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import (
     abstract_params, decode_step, init_cache, init_params, input_specs,
     loss_fn)
@@ -128,6 +129,7 @@ def main(argv=None):
                     help="measurement log for --observe")
     ap.add_argument("--drift-threshold", type=float, default=0.25)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.smoke else get_config(args.arch)
     plan = None

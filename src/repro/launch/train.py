@@ -12,18 +12,21 @@ resulting execution plan's axis rules.
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass, field
 import json
 import os
 import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import latest_step, load_checkpoint, save_checkpoint
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.data import SyntheticDataset
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.optim.adam import AdamW
 
@@ -59,6 +62,24 @@ def resolve_pipeline(plan, mode: str):
           f"(schedule={sched}, placement={list(sp.placement)}, "
           f"sync={[s.sync for s in sp.stages]})", flush=True)
     return sp
+
+
+@dataclass
+class TrainRun:
+    """What a training run leaves behind: per-step loss, global grad norm
+    (before clipping) and wall seconds from dispatch until the step's
+    outputs are ready (host batch generation excluded; the first step
+    includes compilation), plus the final params and optimizer state."""
+    losses: list = field(default_factory=list)
+    grad_norms: list = field(default_factory=list)
+    step_s: list = field(default_factory=list)
+    params: object = None
+    opt_state: object = None
+
+    def record(self, seconds, loss, grad_norm):
+        self.step_s.append(seconds)
+        self.losses.append(float(loss))
+        self.grad_norms.append(float(grad_norm))
 
 
 def _stage_key(s: int) -> str:
@@ -168,10 +189,10 @@ def run_pipeline(args, cfg, stage_plan):
     for d in pre.warnings():
         print(f"preflight: {d.format()}", flush=True)
 
-    params = init_params(cfg, jax.random.PRNGKey(args.seed))
     splits = stage_plan.layer_splits(cfg.num_periods, n_chunks=n_chunks)
     stage_params, fns, mb_keys, tied = split_model(
-        cfg, params, stage_plan.n_stages * n_chunks, splits=splits)
+        cfg, init_params(cfg, jax.random.PRNGKey(args.seed)),
+        stage_plan.n_stages * n_chunks, splits=splits)
 
     store = None
     if args.telemetry_dir:
@@ -194,10 +215,12 @@ def run_pipeline(args, cfg, stage_plan):
         runner = PipelineRunner(fns, stage_plan, device_sets, **runner_kw)
 
     opt = AdamW(lr=args.lr)
+    # the whole model was built on the default device: once each stage's
+    # slice is committed to its own devices, drop the default-device copy
     params_list = runner.place_params(stage_params)
+    del stage_params
     n_virtual = len(params_list)
-    opt_state_list = [runner.place(runner.phys(u), opt.init(p))
-                      for u, p in enumerate(params_list)]
+    opt_state_list = [opt.init(p) for p in params_list]  # on p's devices
     start_step = 0
     if getattr(args, "resume", False) and args.ckpt_dir \
             and latest_step(args.ckpt_dir) is not None:
@@ -226,19 +249,24 @@ def run_pipeline(args, cfg, stage_plan):
     # assume the full CLI surface
     trace_dir = getattr(args, "trace_dir", None)
     record_steps = store is not None or bool(trace_dir)
-    losses = []
+    run = TrainRun()
+    losses = run.losses
     t_start = time.time()
     for step in range(start_step, args.steps):
         batch = jax.tree.map(jnp.asarray, ds.batch(step))
+        t_dispatch = time.perf_counter()
         params_list, opt_state_list, metrics = step_fn(
             params_list, opt_state_list, jnp.asarray(step, jnp.int32),
             batch, record=record_steps)
-        losses.append(metrics["loss"])
+        jax.block_until_ready((params_list, opt_state_list))
+        run.record(time.perf_counter() - t_dispatch, metrics["loss"],
+                   metrics["grad_norm"])
         if step % args.log_every == 0:
             chunks = f"x{n_chunks}v" if n_chunks > 1 else ""
             print(f"step {step:5d} loss={metrics['loss']:.4f} "
                   f"ce={metrics['ce']:.4f} "
                   f"gnorm={metrics['grad_norm']:.3f} "
+                  f"step_ms={run.step_s[-1] * 1e3:.1f} "
                   f"[pipeline {schedule} x{stage_plan.n_stages}{chunks}]",
                   flush=True)
         if args.ckpt_dir and args.ckpt_every and \
@@ -273,10 +301,120 @@ def run_pipeline(args, cfg, stage_plan):
         print(f"trace: wrote {path} "
               f"({len(runner.last_stats.events)} events)", flush=True)
     _drain_tracer_to_spool(spool)
-    return losses
+    run.params, run.opt_state = params_list, opt_state_list
+    return run
 
 
-def main(argv=None):
+def run_single(args, cfg, devices=None):
+    """Train on one data-parallel mesh over ``devices`` (default: every
+    local device) with the jitted single-program step.
+
+    Returns the ``TrainRun``."""
+    mesh = mesh_mod.make_host_mesh(devices)
+    rules = steps_mod.baseline_rules(mesh)
+    opt = AdamW(lr=args.lr)
+    key = jax.random.PRNGKey(args.seed)
+    params = init_params(cfg, key)
+    opt_state = opt.init(params)
+    start_step = 0
+    if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
+        start_step, tree = load_checkpoint(args.ckpt_dir)
+        if _stage_key(0) in tree.get("params", {}):
+            raise ValueError(
+                f"checkpoint in {args.ckpt_dir} is a per-stage pipeline "
+                f"checkpoint — resume it through the pipeline path "
+                f"(--tag-search with the same stage map)")
+        params, opt_state = tree["params"], tree["opt_state"]
+        print(f"resumed from step {start_step}", flush=True)
+    # commit the state to the mesh as the step returns it, so step 1
+    # hits step 0's compiled program instead of compiling a second one
+    params, opt_state = jax.device_put(
+        (params, opt_state), NamedSharding(mesh, P()))
+
+    ds = SyntheticDataset(
+        cfg.vocab_size, args.seq, args.batch, seed=args.seed,
+        frontend_tokens=cfg.frontend_tokens if cfg.frontend != "none" else 0,
+        d_model=cfg.d_model)
+
+    options = steps_mod.StepOptions(loss_chunk=args.loss_chunk)
+    # params and optimizer state are rebound every step: donating them
+    # lets XLA update both in place instead of holding two copies
+    step_fn = jax.jit(steps_mod.make_train_step(cfg, opt, rules, options),
+                      donate_argnums=(0, 1))
+
+    raw_step_fn = step_fn
+    timer = None
+    if args.telemetry_dir:
+        from repro.runtime.telemetry import MeasurementStore, StepTimer
+        timer = StepTimer(MeasurementStore(args.telemetry_dir),
+                          meta={"arch": args.arch, "batch": args.batch,
+                                "seq": args.seq, "launcher": "train",
+                                "run_id": _run_id(args)})
+        step_fn = steps_mod.instrument_step(step_fn, timer)
+
+    # profile one post-warmup step (the first is compile-dominated)
+    profile_at = -1
+    if args.xla_profile:
+        profile_at = min(start_step + 1, args.steps - 1)
+
+    spool = _make_spool(args)
+    run = TrainRun()
+    losses = run.losses
+    t_start = time.time()
+    for step in range(start_step, args.steps):
+        t_step = time.perf_counter()
+        batch = jax.tree.map(jnp.asarray, ds.batch(step))
+        t_dispatch = time.perf_counter()
+        if step == profile_at:
+            from repro.obs.xla_profiler import profile_step
+            log_dir = os.path.join(
+                args.trace_dir or args.telemetry_dir or ".",
+                "xla_profile")
+            t0 = time.perf_counter()
+            out, samples, pmeta = profile_step(
+                raw_step_fn, params, opt_state,
+                jnp.asarray(step, jnp.int32), batch, log_dir=log_dir)
+            wall = time.perf_counter() - t0
+            params, opt_state, metrics = out
+            print(f"xla-profile: {json.dumps(pmeta)} "
+                  f"({len(samples)} collective samples)", flush=True)
+            if timer is not None:
+                timer.record(wall, collectives=samples)
+        else:
+            params, opt_state, metrics = step_fn(
+                params, opt_state, jnp.asarray(step, jnp.int32), batch)
+        jax.block_until_ready((params, opt_state, metrics))
+        run.record(time.perf_counter() - t_dispatch, metrics["loss"],
+                   metrics["grad_norm"])
+        loss = losses[-1]
+        if spool is not None:
+            spool.emit_span(f"step {step}", t_step, time.perf_counter(),
+                            tid=0, cat="train",
+                            args={"step": step, "loss": loss})
+        if step % args.log_every == 0:
+            print(f"step {step:5d} loss={loss:.4f} "
+                  f"ce={float(metrics['ce']):.4f} "
+                  f"gnorm={float(metrics['grad_norm']):.3f} "
+                  f"step_ms={run.step_s[-1] * 1e3:.1f}", flush=True)
+        if args.ckpt_dir and args.ckpt_every and \
+                (step + 1) % args.ckpt_every == 0:
+            save_checkpoint(args.ckpt_dir, step + 1,
+                            {"params": params, "opt_state": opt_state})
+    dt = time.time() - t_start
+    n = max(args.steps - start_step, 1)
+    print(f"done: {n} steps in {dt:.1f}s ({dt/n*1e3:.0f} ms/step); "
+          f"loss {losses[0]:.4f} -> {losses[-1]:.4f}", flush=True)
+    if timer is not None:
+        print(f"telemetry[{args.telemetry_dir}]: "
+              f"{json.dumps(timer.summary())}", flush=True)
+    _drain_tracer_to_spool(spool)
+    run.params, run.opt_state = params, opt_state
+    return run
+
+
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (``run_single``/``run_pipeline`` read
+    the namespace it parses)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="qwen2-1.5b")
     ap.add_argument("--smoke", action="store_true",
@@ -340,15 +478,18 @@ def main(argv=None):
                          "trace and record per-collective samples into "
                          "the telemetry log (no-op if the profiler "
                          "backend is unavailable)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_dir:
         from repro.obs.spans import Tracer, set_tracer
         set_tracer(Tracer(enabled=True))
 
     cfg = get_reduced(args.arch) if args.smoke else get_config(args.arch)
-    mesh = mesh_mod.make_host_mesh()
-    rules = steps_mod.baseline_rules(mesh)
 
     if args.tag_search:
         from repro.core import tag as tag_mod
@@ -366,100 +507,18 @@ def main(argv=None):
         result = tag_mod.optimize(
             lambda p, b: model_loss(red, p, b, remat=False)[0],
             rp, rb, topo, name=args.arch, iterations=24, n_groups=24)
-        plan = lower_strategy(result.strategy, result.gg, topo, mesh,
+        plan = lower_strategy(result.strategy, result.gg, topo,
+                              mesh_mod.make_host_mesh(),
                               n_micro=args.n_micro)
         print(f"TAG plan: speedup={result.speedup:.2f}x "
               f"summary={json.dumps(plan.summary)}", flush=True)
         stage_plan = resolve_pipeline(plan, args.pipeline)
         if stage_plan is not None:
-            losses = run_pipeline(args, cfg, stage_plan)
+            losses = run_pipeline(args, cfg, stage_plan).losses
             _export_spans(args)
             return losses
 
-    opt = AdamW(lr=args.lr)
-    key = jax.random.PRNGKey(args.seed)
-    params = init_params(cfg, key)
-    opt_state = opt.init(params)
-    start_step = 0
-    if args.resume and args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
-        start_step, tree = load_checkpoint(args.ckpt_dir)
-        if _stage_key(0) in tree.get("params", {}):
-            raise ValueError(
-                f"checkpoint in {args.ckpt_dir} is a per-stage pipeline "
-                f"checkpoint — resume it through the pipeline path "
-                f"(--tag-search with the same stage map)")
-        params, opt_state = tree["params"], tree["opt_state"]
-        print(f"resumed from step {start_step}", flush=True)
-
-    ds = SyntheticDataset(
-        cfg.vocab_size, args.seq, args.batch, seed=args.seed,
-        frontend_tokens=cfg.frontend_tokens if cfg.frontend != "none" else 0,
-        d_model=cfg.d_model)
-
-    options = steps_mod.StepOptions(loss_chunk=args.loss_chunk)
-    step_fn = jax.jit(steps_mod.make_train_step(cfg, opt, rules, options))
-
-    raw_step_fn = step_fn
-    timer = None
-    if args.telemetry_dir:
-        from repro.runtime.telemetry import MeasurementStore, StepTimer
-        timer = StepTimer(MeasurementStore(args.telemetry_dir),
-                          meta={"arch": args.arch, "batch": args.batch,
-                                "seq": args.seq, "launcher": "train",
-                                "run_id": _run_id(args)})
-        step_fn = steps_mod.instrument_step(step_fn, timer)
-
-    # profile one post-warmup step (the first is compile-dominated)
-    profile_at = -1
-    if args.xla_profile:
-        profile_at = min(start_step + 1, args.steps - 1)
-
-    spool = _make_spool(args)
-    losses = []
-    t_start = time.time()
-    for step in range(start_step, args.steps):
-        t_step = time.perf_counter()
-        batch = jax.tree.map(jnp.asarray, ds.batch(step))
-        if step == profile_at:
-            from repro.obs.xla_profiler import profile_step
-            log_dir = os.path.join(
-                args.trace_dir or args.telemetry_dir or ".",
-                "xla_profile")
-            t0 = time.perf_counter()
-            out, samples, pmeta = profile_step(
-                raw_step_fn, params, opt_state,
-                jnp.asarray(step, jnp.int32), batch, log_dir=log_dir)
-            wall = time.perf_counter() - t0
-            params, opt_state, metrics = out
-            print(f"xla-profile: {json.dumps(pmeta)} "
-                  f"({len(samples)} collective samples)", flush=True)
-            if timer is not None:
-                timer.record(wall, collectives=samples)
-        else:
-            params, opt_state, metrics = step_fn(
-                params, opt_state, jnp.asarray(step, jnp.int32), batch)
-        loss = float(metrics["loss"])
-        if spool is not None:
-            spool.emit_span(f"step {step}", t_step, time.perf_counter(),
-                            tid=0, cat="train",
-                            args={"step": step, "loss": loss})
-        losses.append(loss)
-        if step % args.log_every == 0:
-            print(f"step {step:5d} loss={loss:.4f} "
-                  f"ce={float(metrics['ce']):.4f} "
-                  f"gnorm={float(metrics['grad_norm']):.3f}", flush=True)
-        if args.ckpt_dir and args.ckpt_every and \
-                (step + 1) % args.ckpt_every == 0:
-            save_checkpoint(args.ckpt_dir, step + 1,
-                            {"params": params, "opt_state": opt_state})
-    dt = time.time() - t_start
-    n = max(args.steps - start_step, 1)
-    print(f"done: {n} steps in {dt:.1f}s ({dt/n*1e3:.0f} ms/step); "
-          f"loss {losses[0]:.4f} -> {losses[-1]:.4f}", flush=True)
-    if timer is not None:
-        print(f"telemetry[{args.telemetry_dir}]: "
-              f"{json.dumps(timer.summary())}", flush=True)
-    _drain_tracer_to_spool(spool)
+    losses = run_single(args, cfg).losses
     _export_spans(args)
     return losses
 

@@ -72,7 +72,8 @@ def attention(cfg, p, x, pos):
     G = H // KV
     q, k, v = _project_qkv(cfg, p, x, pos)
     if cfg.attn_impl == "pallas":
-        # Pallas flash kernel path (TPU target; interpret=True on CPU).
+        # Pallas flash kernel: forward only (differentiating raises);
+        # compiled Mosaic on a TPU, interpreted elsewhere.
         from repro.kernels.ops import gqa_flash_attention
         o = gqa_flash_attention(
             q, k, v, causal=True, window=cfg.sliding_window,

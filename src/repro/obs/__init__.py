@@ -14,8 +14,8 @@ TAG pipeline.
                        histograms, bubble fractions, drift state);
   * ``xla_profiler`` — optional ``jax.profiler`` hook parsing real
                        per-collective samples into
-                       ``StepRecord.collectives`` (graceful no-op when
-                       the profiler is unavailable).
+                       ``StepRecord.collectives`` (no-op off a TPU
+                       when the profiler is unavailable).
 
 The live plane (PR 7) crosses process boundaries:
 

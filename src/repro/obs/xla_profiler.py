@@ -8,11 +8,10 @@ input of ``runtime.calibration.fit_profile``'s per-link-pair tier. This
 closes the ROADMAP telemetry item: real hardware feeds the calibration
 the same samples the replay executors synthesize.
 
-Everything degrades gracefully: when ``jax.profiler`` is missing, the
-trace context raises, or no parseable trace file appears (CPU-only
-backends sometimes emit host tracks only), ``profile_step`` still
-returns the step's output with ``samples == []`` and a ``meta`` dict
-saying why — callers never branch on profiler availability.
+Off a TPU, a missing ``jax.profiler`` or a trace with no parseable file
+(CPU backends sometimes emit host tracks only) degrades to
+``samples == []`` with a ``meta`` dict saying why. A trace that fails,
+and on a TPU any missing profile, raises: the step is never rerun.
 """
 from __future__ import annotations
 
@@ -114,26 +113,27 @@ def parse_trace_collectives(path: str, *, nominal_bw: float = 0.0,
 def profile_step(fn, *args, log_dir: str, nominal_bw: float = 0.0,
                  n_dev: int = 2, link: str = "intra",
                  pair: str | None = None, **kwargs) -> tuple:
-    """Run ``fn(*args, **kwargs)`` under an XLA profiler trace and parse
-    per-collective samples out of the result.
+    """Run ``fn(*args, **kwargs)`` once under an XLA profiler trace and
+    parse per-collective samples out of the result.
 
-    Returns ``(out, samples, meta)``. ``samples`` is [] — never an
-    exception — when the profiler is unavailable, the trace context
-    fails, or no trace file parses; ``meta["profiler"]`` says which
-    (``"ok"``, ``"unavailable"``, ``"trace_failed"``, ``"no_trace"``).
+    Returns ``(out, samples, meta)`` with ``meta["profiler"] == "ok"``. A
+    trace that fails re-raises: ``fn`` is never run a second time (its
+    arguments may be donated). Off a TPU, a missing profiler or trace
+    file degrades to ``samples == []`` with ``meta["profiler"]`` set to
+    ``"unavailable"`` / ``"no_trace"``; on a TPU both raise, since a
+    chip run whose profile silently vanished would mislead.
     """
-    if not profiler_available():
-        return fn(*args, **kwargs), [], {"profiler": "unavailable"}
     import jax
+    on_tpu = jax.default_backend() == "tpu"
+    if not profiler_available():
+        if on_tpu:
+            raise RuntimeError("jax.profiler is unavailable on a TPU backend")
+        return fn(*args, **kwargs), [], {"profiler": "unavailable"}
     import jax.profiler
     os.makedirs(log_dir, exist_ok=True)
-    try:
-        with jax.profiler.trace(log_dir, create_perfetto_trace=True):
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(out)
-    except Exception as e:          # profiler backend refused: run plain
-        return fn(*args, **kwargs), [], {
-            "profiler": "trace_failed", "error": str(e)}
+    with jax.profiler.trace(log_dir, create_perfetto_trace=True):
+        out = fn(*args, **kwargs)
+        jax.block_until_ready(out)
     samples: list = []
     parsed_from = None
     for path in find_trace_files(log_dir):
@@ -146,6 +146,9 @@ def profile_step(fn, *args, log_dir: str, nominal_bw: float = 0.0,
         except (OSError, ValueError, KeyError):
             continue
     if parsed_from is None:
+        if on_tpu:
+            raise RuntimeError(f"profiler wrote no parsable trace under "
+                               f"{log_dir}")
         return out, [], {"profiler": "no_trace", "log_dir": log_dir}
     return out, samples, {"profiler": "ok", "trace_file": parsed_from,
                           "n_collectives": len(samples)}

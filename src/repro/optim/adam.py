@@ -32,8 +32,8 @@ class AdamW:
 def adamw_init(params, state_dtype="float32"):
     dt = jnp.dtype(state_dtype)
 
-    def zeros(p):
-        return jnp.zeros(p.shape, dt)
+    def zeros(p):           # on p's devices, not the default device
+        return jnp.zeros_like(p, dtype=dt)
 
     return {
         "mu": jax.tree.map(zeros, params),

@@ -19,7 +19,7 @@ the real execution engine rather than only in the simulator.
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -79,10 +79,10 @@ def sfb_dense_apply(mesh: Mesh, axis: str, sync: str):
 
     fwd_sm = shard_map(lambda x, w: x @ w, mesh=mesh,
                        in_specs=(P(axis, None), P(None, None)),
-                       out_specs=P(axis, None), check_rep=False)
+                       out_specs=P(axis, None), check_vma=False)
     dx_sm = shard_map(lambda dy, w: dy @ w.T, mesh=mesh,
                       in_specs=(P(axis, None), P(None, None)),
-                      out_specs=P(axis, None), check_rep=False)
+                      out_specs=P(axis, None), check_vma=False)
 
     n_dev = mesh.shape[axis]
 
@@ -98,7 +98,7 @@ def sfb_dense_apply(mesh: Mesh, axis: str, sync: str):
     # dw is identical on every shard after the sync -> replicated out_spec
     dw_sm = shard_map(_dw_local, mesh=mesh,
                       in_specs=(P(axis, None), P(axis, None)),
-                      out_specs=P(None, None), check_rep=False)
+                      out_specs=P(None, None), check_vma=False)
 
     @jax.custom_vjp
     def dense(x, w):

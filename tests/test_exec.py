@@ -916,9 +916,10 @@ def test_pipeline_kill_and_resume_parity():
 
         tmp = tempfile.mkdtemp()
         d1, d2 = os.path.join(tmp, "a"), os.path.join(tmp, "b")
-        full = run_pipeline(mkargs(ckpt_dir=d1), cfg, plan)
+        full = run_pipeline(mkargs(ckpt_dir=d1), cfg, plan).losses
         run_pipeline(mkargs(ckpt_dir=d2, steps=2), cfg, plan)  # "killed"
-        resumed = run_pipeline(mkargs(ckpt_dir=d2, resume=True), cfg, plan)
+        resumed = run_pipeline(mkargs(ckpt_dir=d2, resume=True), cfg,
+                               plan).losses
         assert np.allclose(full[2:], resumed, atol=1e-6), (full, resumed)
         s1, t1 = load_checkpoint(d1)
         s2, t2 = load_checkpoint(d2)

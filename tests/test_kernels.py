@@ -97,3 +97,30 @@ def test_ssd_kernel_matches_model_chunked_path():
     y = mamba_ssd(x, dt, A, Bm, Cm, chunk=64)
     y2, _ = ssd_chunked(x, dt, A, Bm, Cm, 64)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y2), atol=1e-4)
+
+
+def test_flash_attention_grad_raises_plainly():
+    """The kernel is forward-only: training through attn_impl='pallas'
+    must say so, not fail inside Pallas' autodiff."""
+    import jax
+    from repro.configs import get_reduced
+    from repro.models import init_params, loss_fn
+    cfg = get_reduced("qwen2-1.5b").replace(attn_impl="pallas",
+                                             dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks = jnp.ones((1, 128), jnp.int32)
+    batch = {"tokens": toks, "labels": toks}
+    loss, _ = loss_fn(cfg, params, batch, remat=False)   # forward runs
+    assert np.isfinite(float(loss))
+    with pytest.raises(NotImplementedError, match="no backward"):
+        jax.grad(lambda p: loss_fn(cfg, p, batch, remat=False)[0])(params)
+
+
+@pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, expect):
+    import jax
+    from repro.kernels import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_interpret(None) is expect
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False

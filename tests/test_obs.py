@@ -470,6 +470,34 @@ def test_profile_step_no_trace(monkeypatch, tmp_path):
     assert meta["profiler"] == "no_trace"
 
 
+def test_profile_step_trace_failure_reraises_without_rerun(monkeypatch,
+                                                          tmp_path):
+    class FailingCtx:
+        def __init__(self, *a, **k):
+            pass
+
+        def __enter__(self):
+            raise RuntimeError("profiler refused")
+
+        def __exit__(self, *exc):
+            return False
+
+    import jax
+    monkeypatch.setattr(jax.profiler, "trace", FailingCtx)
+    calls = []
+    with pytest.raises(RuntimeError, match="profiler refused"):
+        xp.profile_step(lambda: calls.append(1), log_dir=str(tmp_path))
+    assert calls == []
+
+
+def test_profile_step_missing_profile_raises_on_tpu(monkeypatch, tmp_path):
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(xp, "profiler_available", lambda: False)
+    with pytest.raises(RuntimeError, match="unavailable"):
+        xp.profile_step(lambda: 7, log_dir=str(tmp_path))
+
+
 def test_attach_collectives():
     from repro.runtime.telemetry import StepRecord
     rec = StepRecord(collectives=[{"kind": "xfer"}])

@@ -31,6 +31,36 @@ def test_checkpoint_resume_continues(tmp_path):
     assert len(losses) == 4   # resumed from step 4
 
 
+def test_run_single_on_one_device_times_each_step():
+    import math
+    from repro.launch.train import build_parser, run_single
+    args = build_parser().parse_args(
+        ["--steps", "3", "--batch", "2", "--seq", "32", "--loss-chunk",
+         "16", "--log-every", "100"])
+    run = run_single(args, get_reduced("qwen2-1.5b"),
+                     devices=jax.devices()[:1])
+    assert len(run.losses) == len(run.grad_norms) == len(run.step_s) == 3
+    assert all(math.isfinite(v) for v in run.losses + run.grad_norms)
+    assert all(t > 0 for t in run.step_s)
+    assert jax.tree.leaves(run.params)[0].devices() == {jax.devices()[0]}
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # JAX's own read
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert (compile_cache.CHECKOUT / "pyproject.toml").exists()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:    # later tests in this process compile without the cache
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_serving_generates_tokens():
     cfg = get_reduced("jamba-v0.1-52b")
     params = init_params(cfg, jax.random.PRNGKey(0))
