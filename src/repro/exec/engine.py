@@ -55,8 +55,26 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import numpy as np
 
 from repro.exec.schedule import flatten_schedule, make_schedule
+from repro.obs.spans import span
 from repro.parallel.sfb_dense import tree_grad_sync
 from repro.verify.diagnostics import PlanVerificationError
+
+
+def named(name: str, fn):
+    """``fn`` under a stable ``name``: ``jax.jit`` calls the program
+    ``jit_<name>``, which is how the device trace's ``XLA Modules`` line
+    tells the engine's programs apart."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+@jax.jit
+def grad_accum(acc, g):
+    """Add one microbatch's parameter gradient to the running sum: one
+    program (``jit_grad_accum``) instead of one eager add per leaf."""
+    return jax.tree.map(jnp.add, acc, g)
 
 
 def _batch_spec(x, ndev: int):
@@ -199,6 +217,9 @@ class PipelineRunner:
         self._bwd_act = [None] * self.U      # zb: dc only
         self._bwd_wgt = [None] * self.U      # zb: dp only
         self.last_stats = None               # StepStats of the last step
+        self.steps_run = 0                   # the span args' step number
+        self._dev_ids = [",".join(str(d.id) for d in devs)
+                         for devs in self.device_sets]
 
     # ------------------------------------------------------- placement
     def phys(self, u: int) -> int:
@@ -222,6 +243,16 @@ class PipelineRunner:
         shardings = jax.tree.map(lambda sp: NamedSharding(mesh, sp), specs,
                                  is_leaf=lambda x: isinstance(x, P))
         return jax.device_put(tree, shardings)
+
+    def device_ids(self, u: int) -> str:
+        """Ids of the devices hosting virtual stage ``u``, as the spans'
+        ``devices`` arg (``"0"``, ``"0,1"``)."""
+        return self._dev_ids[self.phys(u)]
+
+    def _transfer(self, s: int, tree, what: str, tags: dict):
+        """A boundary ``place`` under a ``pipeline.transfer`` span."""
+        with span("pipeline.transfer", "pipeline", what=what, **tags):
+            return self.place(s, tree, batch=True)
 
     def place_params(self, params_list) -> list:
         return [self.place(self.phys(u), p)
@@ -336,57 +367,74 @@ class PipelineRunner:
         B = self._make_bodies(u, p_ex, c_ex, mb_ex)
         mesh = B["mesh"]
         if mesh is None:
-            self._fwd[u] = jax.jit(B["fwd"])
+            self._fwd[u] = jax.jit(named("stage_fwd", B["fwd"]))
             if self.has_w:
-                self._bwd_act[u] = jax.jit(B["bwd_act"])
-                self._bwd_wgt[u] = jax.jit(B["bwd_wgt"])
+                self._bwd_act[u] = jax.jit(named("stage_bwd_act",
+                                                 B["bwd_act"]))
+                self._bwd_wgt[u] = jax.jit(named("stage_bwd_wgt",
+                                                 B["bwd_wgt"]))
             else:
-                self._bwd[u] = jax.jit(B["bwd"])
+                self._bwd[u] = jax.jit(named("stage_bwd", B["bwd"]))
             return
 
-        self._fwd[u] = jax.jit(shard_map(
+        self._fwd[u] = jax.jit(named("stage_fwd", shard_map(
             B["fwd"], mesh=mesh,
             in_specs=(B["p_specs"], B["c_specs"], B["mb_specs"]),
-            out_specs=B["fwd_out_specs"], check_vma=False))
+            out_specs=B["fwd_out_specs"], check_vma=False)))
         in_specs = (B["p_specs"], B["c_specs"], B["mb_specs"],
                     B["dout_specs"])
         if self.has_w:
-            self._bwd_act[u] = jax.jit(shard_map(
+            self._bwd_act[u] = jax.jit(named("stage_bwd_act", shard_map(
                 B["bwd_act"], mesh=mesh, in_specs=in_specs,
-                out_specs=B["c_specs"], check_vma=False))
-            self._bwd_wgt[u] = jax.jit(shard_map(
+                out_specs=B["c_specs"], check_vma=False)))
+            self._bwd_wgt[u] = jax.jit(named("stage_bwd_wgt", shard_map(
                 B["bwd_wgt"], mesh=mesh, in_specs=in_specs,
-                out_specs=B["p_specs"], check_vma=False))
+                out_specs=B["p_specs"], check_vma=False)))
         else:
-            self._bwd[u] = jax.jit(shard_map(
+            self._bwd[u] = jax.jit(named("stage_bwd", shard_map(
                 B["bwd"], mesh=mesh, in_specs=in_specs,
-                out_specs=(B["p_specs"], B["c_specs"]), check_vma=False))
+                out_specs=(B["p_specs"], B["c_specs"]), check_vma=False)))
 
     # ------------------------------------------------------------- step
     def step(self, params_list, batch, *, record: bool = False) -> tuple:
-        """One pipelined train step.
+        """One pipelined train step, under a ``pipeline.step`` span.
 
         Returns ``(grads_list, StepStats)``; grads match the structure of
         ``params_list`` (one entry per virtual stage; tied-head gradient
         already folded back into the stage-0 embedding).
         """
+        self.steps_run += 1
+        with span("pipeline.step", "pipeline", step=self.steps_run):
+            return self._step(params_list, batch, record=record)
+
+    def _read(self, x, what: str, **tags) -> float:
+        """One blocking device-to-host read, under a ``pipeline.sync``
+        span that says what it reads."""
+        with span("pipeline.sync", "pipeline", what=what,
+                  step=self.steps_run, **tags):
+            return float(x)
+
+    def _step(self, params_list, batch, *, record: bool) -> tuple:
         t_start = time.perf_counter()
         record = record or self.spool is not None   # spooling needs events
         mbs = split_microbatches(batch, self.n_micro)
         S, U, M = self.S, self.U, self.n_micro
+        k = self.steps_run
 
         params_eff = list(params_list)
         if self.tied_ref is not None:
             src_key, dst_key = self.tied_ref
-            head = self.place(self.phys(U - 1), params_list[0][src_key])
+            with span("pipeline.tied_head", "pipeline", step=k,
+                      stage=self.phys(U - 1), devices=self.device_ids(U - 1)):
+                head = self.place(self.phys(U - 1), params_list[0][src_key])
             params_eff[U - 1] = dict(params_list[U - 1], **{dst_key: head})
 
         mb_cache: dict = {}             # (u, m) -> placed microbatch
 
-        def mb_at(u, m):
+        def mb_at(u, m, tags):
             if (u, m) not in mb_cache:
-                mb_cache[(u, m)] = self.place(
-                    self.phys(u), self._mb_for(u, mbs[m]), batch=True)
+                mb_cache[(u, m)] = self._transfer(
+                    self.phys(u), self._mb_for(u, mbs[m]), "mb", tags)
             return mb_cache[(u, m)]
 
         outs: dict = {}                 # (u, m) -> stage output carry
@@ -401,64 +449,70 @@ class PipelineRunner:
         for ev in self.flat:
             s, m = ev.stage, ev.mb
             u = ev.chunk * S + s
+            tags = {"step": k, "stage": s, "mb": m, "chunk": ev.chunk,
+                    "devices": self._dev_ids[s]}
             t0 = time.perf_counter()
             if ev.kind == "F":
-                carry = None
-                if u > 0:
-                    carry = self.place(s, outs.pop((u - 1, m)), batch=True)
-                stage_in[(u, m)] = carry
-                stash += 1
-                peak = max(peak, stash)
-                mb = mb_at(u, m)
-                if self._fwd[u] is None:
-                    self._build(u, params_eff[u], carry, mb)
-                out = self._fwd[u](params_eff[u], carry, mb)
+                with span("pipeline.F", "pipeline", program="stage_fwd",
+                          **tags):
+                    carry = None
+                    if u > 0:
+                        carry = self._transfer(s, outs.pop((u - 1, m)),
+                                               "carry", tags)
+                    stage_in[(u, m)] = carry
+                    stash += 1
+                    peak = max(peak, stash)
+                    mb = mb_at(u, m, tags)
+                    if self._fwd[u] is None:
+                        self._build(u, params_eff[u], carry, mb)
+                    out = self._fwd[u](params_eff[u], carry, mb)
                 if u == U - 1:
                     loss, mets = out
                     losses.append(loss)
                     mets_acc.append(mets)
                 else:
                     outs[(u, m)] = out
-                if record:
-                    jax.block_until_ready(out)
+                done = out
             elif ev.kind == "B":
-                if u == U - 1:
-                    dout = jnp.asarray(seed_last, jnp.float32)
-                else:
-                    dout = self.place(s, dcs.pop((u + 1, m)), batch=True)
+                program = "stage_bwd_act" if self.has_w else "stage_bwd"
+                with span("pipeline.B", "pipeline", program=program,
+                          **tags):
+                    if u == U - 1:
+                        dout = jnp.asarray(seed_last, jnp.float32)
+                    else:
+                        dout = self._transfer(s, dcs.pop((u + 1, m)),
+                                              "dout", tags)
+                    if self.has_w:
+                        # zero-bubble: activation grad only; the stash
+                        # (and dout) stay pinned until this microbatch's W
+                        carry = stage_in[(u, m)]
+                        dc = self._bwd_act[u](params_eff[u], carry,
+                                              mb_at(u, m, tags), dout)
+                        w_dout[(u, m)] = dout
+                    else:
+                        carry = stage_in.pop((u, m))
+                        stash -= 1
+                        dp, dc = self._bwd[u](params_eff[u], carry,
+                                              mb_at(u, m, tags), dout)
+                if u > 0:
+                    dcs[(u, m)] = dc
                 if self.has_w:
-                    # zero-bubble: activation grad only; the stash (and
-                    # dout) stay pinned until this microbatch's W
-                    carry = stage_in[(u, m)]
-                    dc = self._bwd_act[u](params_eff[u], carry,
-                                          mb_at(u, m), dout)
-                    w_dout[(u, m)] = dout
-                    if u > 0:
-                        dcs[(u, m)] = dc
-                    if record:
-                        jax.block_until_ready(dc)
+                    done = dc
                 else:
+                    grads[u] = self._accumulate(grads[u], dp, tags)
+                    done = dp
+            else:                       # "W": weight grad, releases stash
+                with span("pipeline.W", "pipeline", program="stage_bwd_wgt",
+                          **tags):
                     carry = stage_in.pop((u, m))
                     stash -= 1
-                    dp, dc = self._bwd[u](params_eff[u], carry,
-                                          mb_at(u, m), dout)
-                    grads[u] = dp if grads[u] is None else jax.tree.map(
-                        jnp.add, grads[u], dp)
-                    if u > 0:
-                        dcs[(u, m)] = dc
-                    if record:
-                        jax.block_until_ready(dp)
-            else:                       # "W": weight grad, releases stash
-                carry = stage_in.pop((u, m))
-                stash -= 1
-                dout = w_dout.pop((u, m))
-                dp = self._bwd_wgt[u](params_eff[u], carry, mb_at(u, m),
-                                      dout)
-                grads[u] = dp if grads[u] is None else jax.tree.map(
-                    jnp.add, grads[u], dp)
-                if record:
-                    jax.block_until_ready(dp)
+                    dout = w_dout.pop((u, m))
+                    dp = self._bwd_wgt[u](params_eff[u], carry,
+                                          mb_at(u, m, tags), dout)
+                grads[u] = self._accumulate(grads[u], dp, tags)
+                done = dp
             if record:
+                jax.block_until_ready(done)
                 events.append((ev.kind, s, m,
                                time.perf_counter() - t0, ev.chunk,
                                t0 - t_start))
@@ -466,17 +520,20 @@ class PipelineRunner:
         grads = [jax.tree.map(lambda g: g / M, g_u) for g_u in grads]
         if self.tied_ref is not None:
             src_key, dst_key = self.tied_ref
-            dhead = grads[U - 1].pop(dst_key)
-            dhead = self.place(0, dhead)
-            grads[0] = dict(grads[0], **{
-                src_key: grads[0][src_key] + dhead})
+            with span("pipeline.tied_grad", "pipeline", step=k, stage=0,
+                      devices=self._dev_ids[0]):
+                dhead = grads[U - 1].pop(dst_key)
+                dhead = self.place(0, dhead)
+                grads[0] = dict(grads[0], **{
+                    src_key: grads[0][src_key] + dhead})
 
-        loss = float(jnp.mean(jnp.concatenate(
-            [jnp.atleast_1d(x) for x in losses])))
+        loss = self._read(jnp.mean(jnp.concatenate(
+            [jnp.atleast_1d(x) for x in losses])), "loss")
         metrics = {}
-        for k in mets_acc[0]:
-            metrics[k] = float(np.mean(
-                [float(jnp.mean(mm[k])) for mm in mets_acc]))
+        for key in mets_acc[0]:
+            metrics[key] = float(np.mean(
+                [self._read(jnp.mean(mm[key]), "metric", key=key, mb=i)
+                 for i, mm in enumerate(mets_acc)]))
         wall = time.perf_counter() - t_start
         stats = StepStats(loss=loss, metrics=metrics, wall_time=wall,
                           events=events, peak_stash=peak)
@@ -487,6 +544,14 @@ class PipelineRunner:
         if self.spool is not None:
             self._spool_events(stats, t_start)
         return grads, stats
+
+    def _accumulate(self, acc, g, tags: dict):
+        """Running sum of one virtual stage's parameter gradient."""
+        if acc is None:
+            return g
+        with span("pipeline.grad_accum", "pipeline", program="grad_accum",
+                  **tags):
+            return grad_accum(acc, g)
 
     # -------------------------------------------------------- telemetry
     def _record_telemetry(self, stats: StepStats):
@@ -676,12 +741,14 @@ class CompiledPipelineRunner(PipelineRunner):
 
         mesh = B["mesh"]
         if mesh is None:
-            self._fscan[u] = jax.jit(f_scan)
+            self._fscan[u] = jax.jit(named("stage_fwd_scan", f_scan))
             if self.has_w:
-                self._bscan_act[u] = jax.jit(b_scan_act)
-                self._bscan_wgt[u] = jax.jit(b_scan_wgt)
+                self._bscan_act[u] = jax.jit(named("stage_bwd_act_scan",
+                                                   b_scan_act))
+                self._bscan_wgt[u] = jax.jit(named("stage_bwd_wgt_scan",
+                                                   b_scan_wgt))
             else:
-                self._bscan[u] = jax.jit(b_scan)
+                self._bscan[u] = jax.jit(named("stage_bwd_scan", b_scan))
             return
 
         cs_specs = self._stack_specs(B["c_specs"])
@@ -689,24 +756,24 @@ class CompiledPipelineRunner(PipelineRunner):
         outs_specs = self._stack_specs(B["fwd_out_specs"])
         douts_specs = self._stack_specs(B["dout_specs"])
         p_specs = B["p_specs"]
-        self._fscan[u] = jax.jit(shard_map(
+        self._fscan[u] = jax.jit(named("stage_fwd_scan", shard_map(
             f_scan, mesh=mesh, in_specs=(p_specs, cs_specs, mbs_specs),
-            out_specs=outs_specs, check_vma=False))
+            out_specs=outs_specs, check_vma=False)))
         in_specs = (p_specs, cs_specs, mbs_specs, douts_specs)
         if self.has_w:
-            self._bscan_act[u] = jax.jit(shard_map(
+            self._bscan_act[u] = jax.jit(named("stage_bwd_act_scan", shard_map(
                 b_scan_act, mesh=mesh, in_specs=in_specs,
-                out_specs=cs_specs, check_vma=False))
-            self._bscan_wgt[u] = jax.jit(shard_map(
+                out_specs=cs_specs, check_vma=False)))
+            self._bscan_wgt[u] = jax.jit(named("stage_bwd_wgt_scan", shard_map(
                 b_scan_wgt, mesh=mesh, in_specs=in_specs,
-                out_specs=p_specs, check_vma=False))
+                out_specs=p_specs, check_vma=False)))
         else:
-            self._bscan[u] = jax.jit(shard_map(
+            self._bscan[u] = jax.jit(named("stage_bwd_scan", shard_map(
                 b_scan, mesh=mesh, in_specs=in_specs,
-                out_specs=(p_specs, cs_specs), check_vma=False))
+                out_specs=(p_specs, cs_specs), check_vma=False)))
 
     # ------------------------------------------------------------- step
-    def step(self, params_list, batch, *, record: bool = False) -> tuple:
+    def _step(self, params_list, batch, *, record: bool) -> tuple:
         """One pipelined train step via the scan programs.
 
         Returns ``(grads_list, StepStats)`` under the same gradient
@@ -809,8 +876,9 @@ class CompiledPipelineRunner(PipelineRunner):
             grads[0] = dict(grads[0], **{
                 src_key: grads[0][src_key] + dhead})
 
-        loss = float(jnp.mean(losses))
-        metrics = {k: float(jnp.mean(mets[k])) for k in mets}
+        loss = self._read(jnp.mean(losses), "loss")
+        metrics = {k: self._read(jnp.mean(mets[k]), "metric", key=k)
+                   for k in mets}
         wall = time.perf_counter() - t_start
         stats = StepStats(loss=loss, metrics=metrics, wall_time=wall,
                           events=events, peak_stash=U * M)

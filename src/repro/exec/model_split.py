@@ -68,13 +68,14 @@ def _last_stage(cfg: ModelConfig, tied: bool):
     def fn(p, carry, mb):
         x, aux = carry
         x, aux = _run_blocks(cfg, p, x, aux)
-        h = rms_norm(x, p["final_norm"], cfg.norm_eps)
-        n_prefix = h.shape[1] - mb["labels"].shape[1]
-        if n_prefix:
-            h = h[:, n_prefix:]
-        w = p[TIED_HEAD] if tied else p["head"]
-        ce = cross_entropy(h @ w.T if tied else h @ w, mb["labels"])
-        loss = ce + model_mod.MAX_SMOKE_AUX * jnp.mean(aux)
+        with jax.named_scope("head_ce"):
+            h = rms_norm(x, p["final_norm"], cfg.norm_eps)
+            n_prefix = h.shape[1] - mb["labels"].shape[1]
+            if n_prefix:
+                h = h[:, n_prefix:]
+            w = p[TIED_HEAD] if tied else p["head"]
+            ce = cross_entropy(h @ w.T if tied else h @ w, mb["labels"])
+            loss = ce + model_mod.MAX_SMOKE_AUX * jnp.mean(aux)
         return loss, {"ce": ce, "aux": jnp.mean(aux)}
     return fn
 

@@ -123,31 +123,49 @@ def make_pipeline_train_step(opt: AdamW, runner,
     stays on the stage's devices); gradient clipping is by the GLOBAL
     norm across stages — per-stage squared norms are tiny scalars, so
     the cross-stage reduction happens on host like a real multi-host
-    trainer's scalar allreduce.
+    trainer's scalar allreduce. Each squared norm's dispatch is a
+    ``step.grad_sqnorm`` span and its read a ``pipeline.sync`` span;
+    each stage's update is a ``step.optimizer`` span.
     """
-    import jax.numpy as jnp
+    from repro.obs.spans import span
     from repro.optim.adam import global_norm
 
     options = options if options is not None else StepOptions()
-    sq = jax.jit(lambda g: global_norm(g) ** 2)
 
-    upd = jax.jit(
-        lambda p, s, g, step, scale: opt.update(
-            p, s,
-            jax.tree.map(lambda gg: (gg.astype(jnp.float32)
-                                     * scale).astype(gg.dtype), g),
-            step))
+    def grad_sqnorm(g):
+        with jax.named_scope("optimizer"):
+            return global_norm(g) ** 2
+
+    def adamw_update(p, s, g, step, scale):
+        with jax.named_scope("optimizer"):
+            g = jax.tree.map(lambda gg: (gg.astype(jnp.float32)
+                                         * scale).astype(gg.dtype), g)
+        return opt.update(p, s, g, step)
+
+    sq = jax.jit(grad_sqnorm)
+    upd = jax.jit(adamw_update)
 
     def step_fn(params_list, opt_state_list, step, batch, *,
                 record: bool = False):
         grads, stats = runner.step(params_list, batch, record=record)
-        gnorm = float(sum(float(sq(g)) for g in grads)) ** 0.5
+        k = runner.steps_run
+        sqnorms = []
+        for u, g in enumerate(grads):
+            tags = {"step": k, "stage": u, "devices": runner.device_ids(u)}
+            with span("step.grad_sqnorm", "pipeline", program="grad_sqnorm",
+                      **tags):
+                x = sq(g)
+            with span("pipeline.sync", "pipeline", what="grad_norm", **tags):
+                sqnorms.append(float(x))
+        gnorm = float(sum(sqnorms)) ** 0.5
         scale = jnp.asarray(min(1.0, options.clip_norm / max(gnorm, 1e-9)),
                             jnp.float32)
         new_p, new_s = [], []
-        for p, s, g in zip(params_list, opt_state_list, grads,
-                           strict=True):
-            p2, s2 = upd(p, s, g, step, scale)
+        for u, (p, s, g) in enumerate(zip(params_list, opt_state_list,
+                                          grads, strict=True)):
+            with span("step.optimizer", "pipeline", program="adamw_update",
+                      step=k, stage=u, devices=runner.device_ids(u)):
+                p2, s2 = upd(p, s, g, step, scale)
             new_p.append(p2)
             new_s.append(s2)
         metrics = dict(stats.metrics, loss=stats.loss, grad_norm=gnorm,

@@ -53,6 +53,7 @@ def _head(cfg, params, h):
     return h @ w
 
 
+@jax.named_scope("embed")
 def _embed_inputs(cfg, params, batch):
     """Token (+ prefix) embedding. Returns (x, pos, n_prefix)."""
     x = params["embed"][batch["tokens"]]
@@ -73,35 +74,41 @@ def forward(cfg: ModelConfig, params, batch, remat: bool = True,
     x, pos, n_prefix = _embed_inputs(cfg, params, batch)
     x, aux = tf_mod.stack_fwd(cfg, params["blocks"], x, pos, remat=remat,
                               remat_policy=remat_policy)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("head_ce"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux, n_prefix
 
 
+@jax.named_scope("loss")
 def loss_fn(cfg: ModelConfig, params, batch, remat: bool = True,
             loss_chunk: int = 0, remat_policy: str = "full"):
     """Mean next-token CE (+ MoE aux). ``loss_chunk`` > 0 computes logits
-    in sequence chunks to avoid materializing (B, S, V)."""
+    in sequence chunks to avoid materializing (B, S, V). The final norm,
+    head and CE run under the ``head_ce`` named scope. The whole loss is
+    the ``loss`` scope, which a differentiating transform wraps
+    (``jvp(loss)``), so the scopes inside it stay plain in op names."""
     h, aux, n_prefix = forward(cfg, params, batch, remat=remat,
                                remat_policy=remat_policy)
-    if n_prefix:
-        h = h[:, n_prefix:]
-    labels = batch["labels"]
-    if loss_chunk and h.shape[1] % loss_chunk == 0 and h.shape[1] > loss_chunk:
-        n = h.shape[1] // loss_chunk
-        hc = h.reshape(h.shape[0], n, loss_chunk, -1).swapaxes(0, 1)
-        lc = labels.reshape(labels.shape[0], n, loss_chunk).swapaxes(0, 1)
+    with jax.named_scope("head_ce"):
+        if n_prefix:
+            h = h[:, n_prefix:]
+        labels = batch["labels"]
+        if loss_chunk and h.shape[1] % loss_chunk == 0 and h.shape[1] > loss_chunk:
+            n = h.shape[1] // loss_chunk
+            hc = h.reshape(h.shape[0], n, loss_chunk, -1).swapaxes(0, 1)
+            lc = labels.reshape(labels.shape[0], n, loss_chunk).swapaxes(0, 1)
 
-        def body(tot, inp):
-            hb, lb = inp
-            return tot + cross_entropy(_head(cfg, params, hb), lb), None
+            def body(tot, inp):
+                hb, lb = inp
+                return tot + cross_entropy(_head(cfg, params, hb), lb), None
 
-        tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hc, lc))
-        ce = tot / n
-    else:
-        logits = _head(cfg, params, h)
-        logits = logical_shard(logits, "batch", "seq", "vocab")
-        ce = cross_entropy(logits, labels)
-    return ce + MAX_SMOKE_AUX * aux, {"ce": ce, "aux": aux}
+            tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hc, lc))
+            ce = tot / n
+        else:
+            logits = _head(cfg, params, h)
+            logits = logical_shard(logits, "batch", "seq", "vocab")
+            ce = cross_entropy(logits, labels)
+        return ce + MAX_SMOKE_AUX * aux, {"ce": ce, "aux": aux}
 
 
 # ------------------------------------------------------------- decoding

@@ -4,7 +4,10 @@
 choice regardless.
 
 Each layer = mixer ('A' attention / 'M' mamba) + optional FFN
-(dense SwiGLU or MoE per cfg.moe_every).
+(dense SwiGLU or MoE per cfg.moe_every). Each sub-block, its pre-norm
+included, runs under a ``jax.named_scope`` (``attention``, ``ssd``,
+``mlp``, ``moe``), so the compiled program's op metadata, and the device
+trace through it, says which block an op belongs to.
 """
 from __future__ import annotations
 
@@ -51,21 +54,31 @@ def stacked_defs(cfg) -> dict:
 
 # --------------------------------------------------------------- forward
 
+def _mixer_scope(ch: str) -> str:
+    return "attention" if ch == "A" else "ssd"
+
+
+def _ffn_scope(cfg, j: int) -> str:
+    return "moe" if _layer_is_moe(cfg, j) else "mlp"
+
+
 def _layer_fwd(cfg, lp, x, pos, j: int, ch: str):
     """Full-sequence layer. Returns (x, aux_loss)."""
-    h = rms_norm(x, lp["norm1"], cfg.norm_eps)
-    if ch == "A":
-        mix, _ = attn.attention(cfg, lp["mixer"], h, pos)
-    else:
-        mix, _ = ssm_mod.mamba_fwd(cfg, lp["mixer"], h)
+    with jax.named_scope(_mixer_scope(ch)):
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+        if ch == "A":
+            mix, _ = attn.attention(cfg, lp["mixer"], h, pos)
+        else:
+            mix, _ = ssm_mod.mamba_fwd(cfg, lp["mixer"], h)
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if cfg.d_ff > 0:
-        h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        if _layer_is_moe(cfg, j):
-            y, aux = moe_mod.moe_fwd(cfg, lp["ffn"], h)
-        else:
-            y = mlp_fwd(lp["ffn"], h)
+        with jax.named_scope(_ffn_scope(cfg, j)):
+            h = rms_norm(x, lp["norm2"], cfg.norm_eps)
+            if _layer_is_moe(cfg, j):
+                y, aux = moe_mod.moe_fwd(cfg, lp["ffn"], h)
+            else:
+                y = mlp_fwd(lp["ffn"], h)
         x = x + y
     return logical_shard(x, "batch", "seq", "embed"), aux
 
@@ -150,18 +163,20 @@ def cache_axes(cfg):
 
 
 def _layer_decode(cfg, lp, lcache, x, pos, j: int, ch: str):
-    h = rms_norm(x, lp["norm1"], cfg.norm_eps)
-    if ch == "A":
-        mix, new_cache = attn.decode_attention(cfg, lp["mixer"], h, lcache, pos)
-    else:
-        mix, new_cache = ssm_mod.mamba_decode(cfg, lp["mixer"], h, lcache)
+    with jax.named_scope(_mixer_scope(ch)):
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+        if ch == "A":
+            mix, new_cache = attn.decode_attention(cfg, lp["mixer"], h, lcache, pos)
+        else:
+            mix, new_cache = ssm_mod.mamba_decode(cfg, lp["mixer"], h, lcache)
     x = x + mix
     if cfg.d_ff > 0:
-        h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        if _layer_is_moe(cfg, j):
-            y, _ = moe_mod.moe_fwd(cfg, lp["ffn"], h)
-        else:
-            y = mlp_fwd(lp["ffn"], h)
+        with jax.named_scope(_ffn_scope(cfg, j)):
+            h = rms_norm(x, lp["norm2"], cfg.norm_eps)
+            if _layer_is_moe(cfg, j):
+                y, _ = moe_mod.moe_fwd(cfg, lp["ffn"], h)
+            else:
+                y = mlp_fwd(lp["ffn"], h)
         x = x + y
     return x, new_cache
 

@@ -5,10 +5,13 @@ TAG pipeline.
                        schedule ``Timeline``s and executed event
                        streams, plus the per-(stage, mb, kind)
                        predicted-vs-executed ``diff_report``;
-  * ``spans``        — low-overhead thread-safe span API (planner path:
+  * ``spans``        — low-overhead thread-safe span API (planner:
                        plan -> store lookup -> policy resolve -> MCTS
-                       playouts with expand/featurize/simulate
-                       sub-spans), exported in the same trace format;
+                       playouts; pipeline engine: step -> F/B/W events,
+                       transfers, host syncs, optimizer), recorded in
+                       memory and exported in the same trace format,
+                       and on the device trace's clock during a
+                       ``jax.profiler`` session;
   * ``metrics``      — counters/gauges/histograms with Prometheus-text
                        and JSON dumps (planner hit rates, plan-latency
                        histograms, bubble fractions, drift state);

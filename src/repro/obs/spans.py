@@ -1,15 +1,28 @@
-"""Structured spans: a low-overhead, thread-safe tracing API.
+"""Structured spans: one low-overhead, thread-safe tracing API, two sinks.
 
-A ``Span`` is one timed region of the planning path (``PlannerService
-.plan`` -> store lookup -> policy resolve -> MCTS playouts with
-expand / featurize / gnn_forward / simulate sub-spans). Spans nest per
-thread (each thread keeps its own open-span stack) and finished spans
-are appended under a lock, so concurrent planners share one tracer.
+A ``Span`` is one timed region of the host program: the planning path
+(``PlannerService.plan`` -> store lookup -> policy resolve -> MCTS
+playouts with expand / featurize / gnn_forward / simulate sub-spans) and
+the pipeline engine (``pipeline.step`` -> one ``pipeline.F`` /
+``pipeline.B`` / ``pipeline.W`` per schedule event with nested
+``pipeline.transfer``s, ``pipeline.sync`` around each blocking
+device-to-host read, ``step.optimizer`` per stage; see
+``docs/observability.md`` for the full list).
 
-The global tracer is DISABLED by default and ``span()`` on a disabled
-tracer returns a shared no-op context manager — no allocation, no
-clock read — so instrumented hot paths (one span per MCTS playout)
-stay effectively free until someone opts in:
+A span goes to either or both of two sinks:
+
+  * the in-memory ``Tracer`` (opt-in, ``tracer.enable()``; ``--trace-dir``
+    turns it on), which keeps ``perf_counter`` times and renders Chrome
+    trace events;
+  * a ``jax.profiler.TraceAnnotation`` whenever a profiler session is
+    active (``jax.profiler.trace``), so the span lands in the
+    ``.xplane.pb`` on the device ops' clock, with its args as stats.
+
+With neither sink on, ``span()`` returns a shared no-op context manager:
+no allocation, no clock read, one attribute read and one profiler-state
+check. Spans nest per thread (each thread keeps its own open-span stack)
+and finished spans are appended under a lock, so concurrent planners
+share one tracer:
 
     from repro.obs import get_tracer
     tr = get_tracer()
@@ -20,13 +33,18 @@ stay effectively free until someone opts in:
 
 ``to_chrome`` renders spans in the same Chrome trace-event format as
 ``obs.trace`` renders schedule timelines, so planner spans and pipeline
-timelines open in one viewer.
+timelines open in one viewer. Span args that reach the profiler should
+be strings or numbers.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+_profiling = TraceAnnotation.is_enabled     # a profiler session is active
 
 
 @dataclass
@@ -62,20 +80,30 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    __slots__ = ("tracer", "name", "cat", "args", "_t0")
+    """A span the tracer records; also a profiler annotation while a
+    profiler session is active."""
+
+    __slots__ = ("tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = None
 
     def __enter__(self):
+        if _profiling():
+            self._ann = TraceAnnotation(self.name, **self.args)
+            self._ann.__enter__()
         self._t0 = self.tracer._push()
         return self
 
     def __exit__(self, *exc):
         self.tracer._pop(self.name, self.cat, self._t0, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         return False
 
 
@@ -86,8 +114,9 @@ class _ThreadState(threading.local):
 
 
 class Tracer:
-    """Thread-safe span recorder. Disabled tracers cost one attribute
-    read per ``span()`` call."""
+    """Thread-safe span recorder. A disabled tracer records nothing; its
+    ``span()`` is a profiler annotation during a profiler session and a
+    no-op otherwise."""
 
     def __init__(self, *, enabled: bool = False, max_spans: int = 200_000):
         self.enabled = enabled
@@ -122,10 +151,17 @@ class Tracer:
 
     # --------------------------------------------------------------- spans
     def span(self, name: str, cat: str = "planner", **args):
-        """Context manager timing one region. No-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _SpanCtx(self, name, cat, args)
+        """Context manager timing one region: recorded when the tracer is
+        enabled, annotated on the profiler's clock while a profiler
+        session is active, a no-op otherwise."""
+        return self._span(name, cat, args)
+
+    def _span(self, name: str, cat: str, args: dict):
+        if self.enabled:
+            return _SpanCtx(self, name, cat, args)
+        if _profiling():
+            return TraceAnnotation(name, **args)
+        return _NULL_SPAN
 
     def _tid(self) -> int:
         st = self._local
@@ -213,7 +249,7 @@ def set_tracer(tracer: Tracer) -> Tracer:
 
 def span(name: str, cat: str = "planner", **args):
     """``get_tracer().span(...)`` shorthand for instrumented call sites."""
-    return _GLOBAL.span(name, cat, **args)
+    return _GLOBAL._span(name, cat, args)
 
 
 def export_tracer_metrics(registry, tracer: Tracer | None = None):

@@ -41,6 +41,7 @@ def adamw_init(params, state_dtype="float32"):
     }
 
 
+@jax.named_scope("optimizer")
 def adamw_update(opt: AdamW, params, state, grads, step, lr):
     step = jnp.asarray(step, jnp.int32) + 1
     b1, b2 = opt.b1, opt.b2
@@ -73,6 +74,7 @@ def global_norm(tree):
                         for x in leaves))
 
 
+@jax.named_scope("optimizer")
 def clip_by_global_norm(grads, max_norm: float):
     n = global_norm(grads)
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(n, 1e-9))
