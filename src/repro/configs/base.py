@@ -44,8 +44,10 @@ class ModelConfig:
     sliding_window: int = 0     # 0 = full causal attention
     rope_theta: float = 1e4
     attn_chunk: int = 1024      # query-chunk size of the flash-style scan
-    attn_impl: str = "jnp"      # "jnp" (shardable reference) | "pallas"
-                                # (kernels/flash_attention, forward only)
+    attn_impl: str = "auto"     # "auto": the splash flash kernel (forward
+                                # and backward) on a TPU where it applies,
+                                # else "jnp" (kernels.ops.resolve_attn_impl);
+                                # "pallas" | "jnp" force one path
     # --- modality frontend stub (audio/vlm): number of precomputed
     # frame/patch embeddings prepended to the token sequence.
     frontend: str = "none"      # none | audio | vision

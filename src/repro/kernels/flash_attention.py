@@ -113,7 +113,7 @@ def _forward_only(call):
     def bwd(_res, _g):
         raise NotImplementedError(
             "the Pallas flash_attention kernel has no backward pass; "
-            "train with attn_impl='jnp'")
+            "train through kernels.ops.gqa_splash_attention")
 
     f.defvjp(lambda *xs: (call(*xs), None), bwd)
     return f

@@ -1,6 +1,6 @@
-"""GQA attention: chunked-causal (flash-style online softmax in pure jnp,
-mirrored by kernels/flash_attention.py for TPU), sliding-window variant,
-and single-token decode against a KV cache.
+"""GQA attention for train/prefill (the splash flash kernel on a TPU, a
+query-chunk scan in pure jnp elsewhere), sliding-window variant, and
+single-token decode against a KV cache.
 
 Shapes: q (B, S, H, hd); k/v (B, S, KV, hd). GQA groups G = H // KV.
 """
@@ -8,9 +8,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.kernels.ops import gqa_splash_attention, resolve_attn_impl
 from repro.models.layers import ParamDef, rotary
-from repro.parallel.sharding import logical_shard
+from repro.parallel.sharding import current_rules, logical_shard, logical_spec
 
 NEG_INF = -1e30
 Q_CHUNK = 1024
@@ -60,29 +62,13 @@ def _sdpa_chunk(q, k, v, mask):
     return jnp.einsum("bkgqs,bskh->bqkgh", w, v)
 
 
-def attention(cfg, p, x, pos):
-    """Full (or sliding-window) causal self-attention for train/prefill.
-
-    Scans over query chunks so the (qc, S) score tile is the only softmax
-    temp — the pure-jnp analogue of the Pallas flash kernel.
-    Returns (out (B,S,D), (k, v) for cache use).
-    """
-    B, S, _ = x.shape
-    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    G = H // KV
-    q, k, v = _project_qkv(cfg, p, x, pos)
-    if cfg.attn_impl == "pallas":
-        # Pallas flash kernel: forward only (differentiating raises);
-        # compiled Mosaic on a TPU, interpreted elsewhere.
-        from repro.kernels.ops import gqa_flash_attention
-        o = gqa_flash_attention(
-            q, k, v, causal=True, window=cfg.sliding_window,
-            block_q=min(128, S), block_k=min(128, S))
-        out = o.reshape(B, S, H * hd)
-        out = logical_shard(out, "batch", "seq", "q_heads")
-        return out @ p["wo"], (k, v)
-    qg = q.reshape(B, S, KV, G, hd)
-
+def chunk_attention(cfg, q, k, v, pos):
+    """The jnp path: a scan over query chunks, each against all keys, so
+    the (qc, S) score tile is the only softmax temp. q (B, S, H, hd),
+    k/v (B, S, KV, hd) -> (B, S, H, hd)."""
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, S, KV, H // KV, hd)
     qc = min(cfg.attn_chunk or Q_CHUNK, S)
     assert S % qc == 0
     n_chunks = S // qc
@@ -97,10 +83,63 @@ def attention(cfg, p, x, pos):
         o = _sdpa_chunk(q_blk, k, v, causal)
         return carry, o
 
-    q_blocks = qg.reshape(B, n_chunks, qc, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
+    q_blocks = qg.reshape(B, n_chunks, qc, KV, H // KV, hd).transpose(
+        1, 0, 2, 3, 4, 5)
     _, outs = jax.lax.scan(body, None, (jnp.arange(n_chunks), q_blocks))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H * hd)
-    out = logical_shard(out, "batch", "seq", "q_heads")
+    return outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, H, hd)
+
+
+def _kernel_batch_axis(q, k):
+    """How the active sharding rules let the kernel run: ``()`` where
+    nothing is sharded, the batch's mesh axis where only the batch is,
+    None where heads or the sequence are sharded."""
+    r = current_rules()
+    if r is None or r.mesh is None:
+        return ()
+    specs = (logical_spec(("batch", "seq", "q_heads", None), q.shape),
+             logical_spec(("batch", "seq", "kv_heads", None), k.shape))
+
+    def split(ax):
+        return ax is not None and r.axis_size(ax) > 1
+    if any(split(ax) for spec in specs for ax in tuple(spec)[1:]):
+        return None
+    batch = tuple(specs[0])[:1]
+    return batch[0] if batch and split(batch[0]) else ()
+
+
+def _kernel_attention(cfg, q, k, v, batch_axis):
+    """The splash kernel, per batch shard under ``shard_map`` where
+    ``batch_axis`` names the mesh axis the batch is sharded over. Shapes
+    as ``chunk_attention``."""
+    def call(q, k, v):
+        return gqa_splash_attention(q, k, v, window=cfg.sliding_window)
+    if not batch_axis:
+        return call(q, k, v)
+    spec = P(batch_axis)
+    return jax.shard_map(call, mesh=current_rules().mesh,
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
+def attention(cfg, p, x, pos):
+    """Full (or sliding-window) causal self-attention for train/prefill.
+
+    ``cfg.attn_impl`` "auto" resolves (``kernels.ops.resolve_attn_impl``)
+    to the splash flash kernel, forward and backward, on a TPU for full
+    causal attention at a sequence its block table tiles, unless heads
+    or the sequence are sharded; to the jnp query-chunk scan everywhere
+    else. "pallas" and "jnp" force one path. ``pos`` is 0..S-1.
+    Returns (out (B,S,D), (k, v) for cache use).
+    """
+    B, S, _ = x.shape
+    q, k, v = _project_qkv(cfg, p, x, pos)
+    impl = resolve_attn_impl(cfg.attn_impl, S, cfg.sliding_window)
+    batch_axis = _kernel_batch_axis(q, k) if impl == "pallas" else None
+    if batch_axis is None and cfg.attn_impl != "pallas":
+        o = chunk_attention(cfg, q, k, v, pos)
+    else:
+        o = _kernel_attention(cfg, q, k, v, batch_axis or ())
+    out = logical_shard(o.reshape(B, S, -1), "batch", "seq", "q_heads")
     return out @ p["wo"], (k, v)
 
 

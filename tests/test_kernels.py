@@ -1,6 +1,13 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp
 oracles in kernels/ref.py (interpret mode executes the kernel bodies on
 CPU)."""
+import json
+import os
+from pathlib import Path
+import subprocess
+import sys
+import textwrap
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -99,21 +106,31 @@ def test_ssd_kernel_matches_model_chunked_path():
     np.testing.assert_allclose(np.asarray(y), np.asarray(y2), atol=1e-4)
 
 
-def test_flash_attention_grad_raises_plainly():
-    """The kernel is forward-only: training through attn_impl='pallas'
-    must say so, not fail inside Pallas' autodiff."""
+@pytest.mark.parametrize("S,window", [(128, 0), (256, 0), (256, 64)])
+def test_splash_attention_grads_match_jnp_attention(S, window):
+    """Loss and dq/dk/dv through the trainable kernel (interpreted here)
+    against the model's jnp query-chunk scan: causal, GQA with G = 3."""
     import jax
     from repro.configs import get_reduced
-    from repro.models import init_params, loss_fn
-    cfg = get_reduced("qwen2-1.5b").replace(attn_impl="pallas",
-                                             dtype="float32")
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    toks = jnp.ones((1, 128), jnp.int32)
-    batch = {"tokens": toks, "labels": toks}
-    loss, _ = loss_fn(cfg, params, batch, remat=False)   # forward runs
-    assert np.isfinite(float(loss))
-    with pytest.raises(NotImplementedError, match="no backward"):
-        jax.grad(lambda p: loss_fn(cfg, p, batch, remat=False)[0])(params)
+    from repro.kernels.ops import gqa_splash_attention
+    from repro.models.attention import chunk_attention
+    cfg = get_reduced("qwen2-1.5b").replace(sliding_window=window,
+                                             attn_chunk=64)
+    B, H, KV, hd = 2, 6, 2, 32
+    q, do = (_rand((B, S, H, hd), jnp.float32) for _ in range(2))
+    k, v = (_rand((B, S, KV, hd), jnp.float32) for _ in range(2))
+
+    def loss(attn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v) * do), argnums=(0, 1, 2))
+    got, got_g = loss(lambda q, k, v: gqa_splash_attention(
+        q, k, v, window=window))(q, k, v)
+    want, want_g = loss(lambda q, k, v: chunk_attention(
+        cfg, q, k, v, jnp.arange(S)))(q, k, v)
+    # a sum of ~1e5 products of order 1: f32 summation order alone moves it
+    np.testing.assert_allclose(float(got), float(want), atol=1e-3)
+    for g, w in zip(got_g, want_g, strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4)
 
 
 @pytest.mark.parametrize("backend,expect", [("cpu", True), ("tpu", False)])
@@ -124,3 +141,85 @@ def test_interpret_mode_follows_backend(monkeypatch, backend, expect):
     assert resolve_interpret(None) is expect
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
+
+
+@pytest.mark.parametrize("impl,backend,seq,window,expect", [
+    ("auto", "tpu", 4096, 0, "pallas"),
+    ("auto", "tpu", 2048, 0, "pallas"),
+    ("auto", "cpu", 4096, 0, "jnp"),
+    ("auto", "tpu", 4096, 4096, "jnp"),       # sliding window
+    ("auto", "tpu", 4000, 0, "jnp"),          # no block size tiles it
+    ("auto", "tpu", 1024, 0, "jnp"),          # below the tuned blocks
+    ("auto", "tpu", 64, 0, "jnp"),
+    ("jnp", "tpu", 4096, 0, "jnp"),
+    ("pallas", "cpu", 4096, 4096, "pallas"),
+])
+def test_attn_impl_auto_follows_backend(monkeypatch, impl, backend, seq,
+                                        window, expect):
+    import jax
+    from repro.kernels.ops import resolve_attn_impl
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_attn_impl(impl, seq, window) == expect
+
+
+@pytest.mark.parametrize("axes,expect", [
+    (None, ()),                                   # no sharding rules
+    ({"data": 2, "model": 1}, "data"),            # batch sharded only
+    ({"data": 1, "model": 1}, ()),
+    ({"data": 2, "model": 2}, None),              # heads sharded: jnp
+])
+def test_kernel_runs_per_batch_shard_only(axes, expect):
+    from jax.sharding import AbstractMesh
+    from repro.launch.steps import baseline_rules
+    from repro.models.attention import _kernel_batch_axis
+    from repro.parallel.sharding import axis_rules
+    q = jnp.zeros((4, 128, 12, 32))
+    k = jnp.zeros((4, 128, 2, 32))
+    if axes is None:
+        assert _kernel_batch_axis(q, k) == expect
+        return
+    mesh = AbstractMesh(tuple(axes.values()), tuple(axes))
+    with axis_rules(baseline_rules(mesh)):
+        assert _kernel_batch_axis(q, k) == expect
+
+
+def test_kernel_under_a_batch_sharded_mesh_trains_as_jnp():
+    """Two CPU devices, batch over "data": the kernel runs inside
+    shard_map per batch shard, and the loss and gradients match the jnp
+    path under the same rules."""
+    code = textwrap.dedent("""
+        import json, jax, jax.numpy as jnp
+        from repro.configs import get_reduced
+        from repro.launch import mesh as mesh_mod, steps as steps_mod
+        from repro.models import init_params, loss_fn
+        from repro.parallel.sharding import axis_rules
+        cfg = get_reduced("qwen2-1.5b").replace(dtype="float32")
+        rules = steps_mod.baseline_rules(mesh_mod.make_host_mesh(jax.devices()))
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, cfg.vocab_size)
+        batch = {"tokens": toks, "labels": toks}
+
+        def run(impl):
+            c = cfg.replace(attn_impl=impl)
+            def f(p):
+                with axis_rules(rules):
+                    return loss_fn(c, p, batch, remat=False)[0]
+            fn = jax.jit(jax.value_and_grad(f))
+            return fn(params), fn.lower(params).as_text()
+        (l_k, g_k), text = run("pallas")
+        (l_j, g_j), _ = run("jnp")
+        gap = max(float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+                  for a, b in zip(jax.tree.leaves(g_k), jax.tree.leaves(g_j)))
+        print(json.dumps({"loss": [float(l_k), float(l_j)], "gap": gap,
+                          "shard_map": "sdy.manual_computation" in text}))
+    """)
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["shard_map"]
+    np.testing.assert_allclose(*got["loss"], rtol=1e-5)
+    assert got["gap"] < 1e-4

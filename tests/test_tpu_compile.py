@@ -12,6 +12,7 @@ each import every test file. Keep these tests in this one file.
 import importlib.util
 import os
 from pathlib import Path
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,10 +22,12 @@ import pytest
 from repro.configs import get_config
 from repro.configs.shapes import InputShape
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.ops import gqa_splash_attention
 from repro.kernels.ssd_scan import ssd_scan
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
 from repro.models import abstract_params, input_specs
+from repro.models.attention import chunk_attention
 from repro.optim.adam import AdamW
 
 V5E_HBM_BYTES = int(15.75 * 2**30)   # what XLA lets one program use
@@ -74,6 +77,43 @@ def test_flash_attention_compiles_for_v5e(one_chip):
         lambda q, k, v: flash_attention(q, k, v, interpret=False)
     ).lower(q, q, q).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_splash_attention_trains_on_v5e(one_chip):
+    """Forward and backward of the trainable kernel at the one-chip cell's
+    shape (batch 2, S 4096, 12 q / 2 kv heads of 128): the kernels are
+    the program, and the f32 score tiles of the jnp scan, (.., 1024,
+    4096) a query chunk, are gone with their temporaries."""
+    cfg = get_config("qwen2-1.5b")
+    B, S, H, KV, hd = 2, 4096, 12, 2, 128
+    q = _spec((B, S, H, hd), jnp.bfloat16, one_chip)
+    kv = _spec((B, S, KV, hd), jnp.bfloat16, one_chip)
+    tile = re.compile(r"f32\[[\d,]*1024,4096\]")
+
+    def compiled(attn):
+        def loss(q, k, v):
+            return jnp.sum(attn(q, k, v).astype(jnp.float32))
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    def scoped(q, k, v):
+        with jax.named_scope("attention"):
+            return gqa_splash_attention(q, k, v, interpret=False)
+    kernel = compiled(scoped)
+    text = kernel.as_text()
+    # the forward (with residuals) and the fused dq/dk/dv backward, each
+    # instruction on one line with its scope, as tools that read the
+    # compiled text line by line find it
+    calls = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) >= 2
+    assert all(re.search(r'metadata=\{op_name="[^"]*attention', ln) for ln in calls)
+    assert not tile.search(text)
+    # a few (B, H, S, hd) arrays (the f32 dq accumulator is 25 MB), not
+    # the scan's gigabytes of score tiles
+    assert kernel.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    scan = compiled(lambda q, k, v: chunk_attention(cfg, q, k, v,
+                                                     jnp.arange(S)))
+    assert tile.search(scan.as_text())
+    assert scan.memory_analysis().temp_size_in_bytes > 2**30
 
 
 def test_ssd_scan_compiles_for_v5e(one_chip):
