@@ -1,6 +1,6 @@
 """Architecture registry: ``get_config(arch)`` / ``get_reduced(arch)``.
 
-Ten assigned architectures (public-literature pool) plus the paper's own
+Eleven architectures (public-literature pool) plus the paper's own
 benchmark models (Table 3 of TAG) used by the strategy-search benchmarks.
 """
 from __future__ import annotations
@@ -23,6 +23,7 @@ _MODULES = {
     "jamba-v0.1-52b": "repro.configs.jamba_v0_1_52b",
     "internvl2-26b": "repro.configs.internvl2_26b",
     "minitron-4b": "repro.configs.minitron_4b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
 }
 
 ARCH_IDS = tuple(_MODULES)

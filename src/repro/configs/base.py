@@ -24,11 +24,24 @@ class ModelConfig:
     qkv_bias: bool = False
     tie_embeddings: bool = False
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0        # routed experts the router scores
     experts_per_token: int = 0
     moe_every: int = 1          # FFN is MoE on layers where idx % moe_every == moe_every-1
     capacity_factor: float = 1.25
     moe_combine: str = "gather"   # "gather" | "scatter" (§Perf lever)
+    router_score: str = "softmax"  # "softmax" | "sigmoid": sigmoid scores,
+                                   # top-k by score + a correction bias
+    route_scale: float = 1.0    # the chosen experts' weights sum to this
+    shared_experts: int = 0     # experts of width d_ff every token takes
+    expert_shards: int = 1      # chips that share each layer's experts
+    # the held experts' correction bias is offset on each batch to give
+    # them their even share of the choices (models.moe.even_share_offset),
+    # where a trained model's bias holds the loads near even
+    even_share: bool = False
+    aux_loss_weight: float = 0.01  # weight of the MoE load-balance loss
+    # leading layers before the scanned periods: attention + dense FFN
+    first_dense_layers: int = 0
+    dense_ff: int = 0           # their FFN width
     # --- SSM (Mamba2/SSD) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -42,6 +55,13 @@ class ModelConfig:
     layer_pattern: str = ""
     # --- attention variant ---
     sliding_window: int = 0     # 0 = full causal attention
+    # latent attention (MLA) where kv_lora_rank > 0: k_nope and v come
+    # from a normed latent of kv_lora_rank, rotary on qk_rope_head_dim
+    # dims with one rotary key shared by all heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     rope_theta: float = 1e4
     attn_chunk: int = 1024      # query-chunk size of the flash-style scan
     attn_impl: str = "auto"     # "auto": the splash flash kernel (forward
@@ -55,8 +75,33 @@ class ModelConfig:
     # --- numerics ---
     dtype: str = "bfloat16"
     norm_eps: float = 1e-5
+    embed_scale: bool = True    # scale the embedding by sqrt(d_model)
     # provenance
     source: str = ""
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """q.k width: nope + rope dims under MLA, else the head dim."""
+        if self.mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.resolved_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this chip computes."""
+        return self.num_experts // self.expert_shards
+
+    @property
+    def dropless(self) -> bool:
+        """MoE layers run the dropless layer (``models.moe.dropless_fwd``):
+        the sigmoid-routed DeepSeek-V3 block, and any share of the experts
+        (the capacity path computes them all). Softmax-routed configs
+        keep the capacity path (``moe_fwd``)."""
+        return self.router_score == "sigmoid" or self.expert_shards > 1
 
     @property
     def resolved_head_dim(self) -> int:
@@ -72,9 +117,10 @@ class ModelConfig:
 
     @property
     def num_periods(self) -> int:
-        p = self.pattern
-        assert self.num_layers % len(p) == 0, (self.name, self.num_layers, p)
-        return self.num_layers // len(p)
+        """Scanned periods, after the leading dense layers."""
+        p, n = self.pattern, self.num_layers - self.first_dense_layers
+        assert n % len(p) == 0, (self.name, self.num_layers, p)
+        return n // len(p)
 
     @property
     def d_inner(self) -> int:
@@ -89,21 +135,35 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     # ---- parameter counting (used for roofline MODEL_FLOPS = 6*N*D) ----
-    def param_count(self, active_only: bool = False) -> int:
-        D, F, V = self.d_model, self.d_ff, self.vocab_size
+    def attention_params(self) -> int:
+        """One attention mixer's parameters."""
+        D, H, KV = self.d_model, self.num_heads, self.num_kv_heads
+        if self.mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            return (D * H * self.qk_head_dim              # q
+                    + D * (r + rope) + r                   # latent + its norm
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * D)             # o
         hd = self.resolved_head_dim
+        n = D * H * hd + 2 * D * KV * hd + H * hd * D      # q, k, v, o
+        if self.qkv_bias:
+            n += (H + 2 * KV) * hd
+        return n
+
+    def param_count(self, active_only: bool = False) -> int:
+        """Parameters this configuration holds (its share of the routed
+        experts); ``active_only``: with the experts one token takes."""
+        D, F, V = self.d_model, self.d_ff, self.vocab_size
         n = V * D  # embedding
         if not self.tie_embeddings:
             n += V * D  # lm head
+        k = self.first_dense_layers
+        n += k * (2 * D + self.attention_params() + 3 * D * self.dense_ff)
         per = self.pattern
         for ch in list(per) * self.num_periods:
             n += 2 * D  # norms
             if ch == "A":
-                n += D * (self.num_heads * hd)          # q
-                n += 2 * D * (self.num_kv_heads * hd)   # k, v
-                n += (self.num_heads * hd) * D          # o
-                if self.qkv_bias:
-                    n += (self.num_heads + 2 * self.num_kv_heads) * hd
+                n += self.attention_params()
             else:  # mamba mixer
                 di, ds, nh = self.d_inner, self.ssm_state, self.ssm_nheads
                 n += D * (2 * di + 2 * self.ssm_ngroups * ds + nh)  # in_proj
@@ -112,15 +172,19 @@ class ModelConfig:
                 n += di * D                                         # out_proj
         # FFNs (attention/mamba mixers both may carry an FFN when d_ff > 0)
         if F > 0:
-            layers_with_ffn = self.num_layers
+            layers_with_ffn = self.num_layers - k
             moe_layers = 0
             if self.num_experts > 0:
                 moe_layers = sum(
-                    1 for i in range(self.num_layers)
+                    1 for i in range(layers_with_ffn)
                     if i % self.moe_every == self.moe_every - 1)
             dense_layers = layers_with_ffn - moe_layers
             n += dense_layers * 3 * D * F
             if self.num_experts > 0:
-                e = self.experts_per_token if active_only else self.num_experts
-                n += moe_layers * (e * 3 * D * F + D * self.num_experts)
+                e = self.experts_per_token if active_only else self.held_experts
+                e += self.shared_experts
+                router = D * self.num_experts
+                if self.router_score == "sigmoid":
+                    router += self.num_experts       # correction bias
+                n += moe_layers * (e * 3 * D * F + router)
         return n

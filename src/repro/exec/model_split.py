@@ -3,7 +3,9 @@
 The decoder stack is a scan over ``num_periods`` period-params (leading
 dim of every leaf under ``params["blocks"]``), so a stage is a contiguous
 period span plus the edges: stage 0 owns the embedding (+ frontend
-projection), the last stage owns the final norm, head, and loss.
+projection) and the leading layers that precede the periods
+(``params["lead"]``), the last stage owns the final norm, head, and
+loss.
 
 Stage functions share one signature the engine understands:
 
@@ -42,6 +44,9 @@ def _first_stage(cfg: ModelConfig):
         del carry
         x, pos, n_prefix = model_mod._embed_inputs(cfg, p, mb)
         del n_prefix
+        if "lead" in p:
+            x, _ = tf_mod.stack_fwd(tf_mod.lead_config(cfg), p["lead"], x,
+                                    pos, remat=False)
         aux = jnp.zeros((x.shape[0],), jnp.float32)
         return _run_blocks(cfg, p, x, aux)
     return fn
@@ -59,8 +64,8 @@ def _run_blocks(cfg, p, x, aux):
     blocks = p.get("blocks")
     if blocks is not None and jax.tree.leaves(blocks):
         pos = jnp.arange(x.shape[1])
-        x, a = tf_mod.stack_fwd(cfg, blocks, x, pos, remat=False)
-        aux = aux + a                   # scalar broadcasts over (B,)
+        x, stats = tf_mod.stack_fwd(cfg, blocks, x, pos, remat=False)
+        aux = aux + stats["aux"]        # scalar broadcasts over (B,)
     return x, aux
 
 
@@ -75,7 +80,7 @@ def _last_stage(cfg: ModelConfig, tied: bool):
                 h = h[:, n_prefix:]
             w = p[TIED_HEAD] if tied else p["head"]
             ce = cross_entropy(h @ w.T if tied else h @ w, mb["labels"])
-            loss = ce + model_mod.MAX_SMOKE_AUX * jnp.mean(aux)
+            loss = ce + cfg.aux_loss_weight * jnp.mean(aux)
         return loss, {"ce": ce, "aux": jnp.mean(aux)}
     return fn
 
@@ -105,6 +110,8 @@ def split_model(cfg: ModelConfig, params, n_stages: int,
         keys: list = []
         if s == 0:
             p["embed"] = params["embed"]
+            if "lead" in params:
+                p["lead"] = params["lead"]
             keys.append("tokens")
             if cfg.frontend != "none":
                 p["frontend_proj"] = params["frontend_proj"]
@@ -132,9 +139,9 @@ def _single_stage(cfg: ModelConfig, tied: bool):
 
     def fn(p, carry, mb):
         carry = first(p, carry, mb)
-        # first() already ran the decoder blocks; hand last() a
-        # blocks-free view so it only applies norm + head + loss
-        p_last = {k: v for k, v in p.items() if k != "blocks"}
+        # first() already ran the decoder layers; hand last() a
+        # layer-free view so it only applies norm + head + loss
+        p_last = {k: v for k, v in p.items() if k not in ("blocks", "lead")}
         if tied:
             p_last["head"] = p["embed"].T
         return last(p_last, carry, mb)

@@ -18,9 +18,6 @@ from repro.models.layers import (
     ParamDef, abstract_tree, axes_tree, cross_entropy, init_tree, rms_norm)
 from repro.parallel.sharding import logical_shard
 
-MAX_SMOKE_AUX = 0.01  # aux-loss weight
-
-
 def model_defs(cfg: ModelConfig) -> dict:
     D, V = cfg.d_model, cfg.vocab_size
     defs = {
@@ -28,6 +25,8 @@ def model_defs(cfg: ModelConfig) -> dict:
         "blocks": tf_mod.stacked_defs(cfg),
         "final_norm": ParamDef((D,), ("embed",), init="ones"),
     }
+    if cfg.first_dense_layers:
+        defs["lead"] = tf_mod.lead_defs(cfg)
     if not cfg.tie_embeddings:
         defs["head"] = ParamDef((D, V), ("embed", "vocab"), scale=0.02)
     if cfg.frontend != "none":
@@ -53,11 +52,15 @@ def _head(cfg, params, h):
     return h @ w
 
 
+def embed_tokens(cfg, params, tokens):
+    x = params["embed"][tokens]
+    return x * (cfg.d_model ** 0.5) if cfg.embed_scale else x
+
+
 @jax.named_scope("embed")
 def _embed_inputs(cfg, params, batch):
     """Token (+ prefix) embedding. Returns (x, pos, n_prefix)."""
-    x = params["embed"][batch["tokens"]]
-    x = x * (cfg.d_model ** 0.5)
+    x = embed_tokens(cfg, params, batch["tokens"])
     n_prefix = 0
     if cfg.frontend != "none":
         prefix = batch["prefix"].astype(x.dtype) @ params["frontend_proj"]
@@ -70,24 +73,28 @@ def _embed_inputs(cfg, params, batch):
 
 def forward(cfg: ModelConfig, params, batch, remat: bool = True,
             remat_policy: str = "full"):
-    """Full-sequence forward. Returns (hidden (B, S, D), aux_loss, n_prefix)."""
+    """Full-sequence forward. Returns (hidden (B, S, D), MoE statistics
+    (``transformer.stack_fwd``), n_prefix)."""
     x, pos, n_prefix = _embed_inputs(cfg, params, batch)
-    x, aux = tf_mod.stack_fwd(cfg, params["blocks"], x, pos, remat=remat,
-                              remat_policy=remat_policy)
+    x, stats = tf_mod.layers_fwd(cfg, params, x, pos, remat=remat,
+                                 remat_policy=remat_policy)
     with jax.named_scope("head_ce"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, aux, n_prefix
+    return x, stats, n_prefix
 
 
 @jax.named_scope("loss")
 def loss_fn(cfg: ModelConfig, params, batch, remat: bool = True,
             loss_chunk: int = 0, remat_policy: str = "full"):
-    """Mean next-token CE (+ MoE aux). ``loss_chunk`` > 0 computes logits
-    in sequence chunks to avoid materializing (B, S, V). The final norm,
-    head and CE run under the ``head_ce`` named scope. The whole loss is
-    the ``loss`` scope, which a differentiating transform wraps
-    (``jvp(loss)``), so the scopes inside it stay plain in op names."""
-    h, aux, n_prefix = forward(cfg, params, batch, remat=remat,
+    """Mean next-token CE + ``cfg.aux_loss_weight`` x the MoE aux loss.
+    Returns (loss, metrics): ``ce``, ``aux`` and the dropless MoE layer's
+    counters (``moe_rows_here``, ``moe_load_max``). ``loss_chunk`` > 0
+    computes logits in sequence chunks to avoid materializing (B, S, V).
+    The final norm, head and CE run under the ``head_ce`` named scope.
+    The whole loss is the ``loss`` scope, which a differentiating
+    transform wraps (``jvp(loss)``), so the scopes inside it stay plain in
+    op names."""
+    h, stats, n_prefix = forward(cfg, params, batch, remat=remat,
                                remat_policy=remat_policy)
     with jax.named_scope("head_ce"):
         if n_prefix:
@@ -108,7 +115,8 @@ def loss_fn(cfg: ModelConfig, params, batch, remat: bool = True,
             logits = _head(cfg, params, h)
             logits = logical_shard(logits, "batch", "seq", "vocab")
             ce = cross_entropy(logits, labels)
-        return ce + MAX_SMOKE_AUX * aux, {"ce": ce, "aux": aux}
+        return (ce + cfg.aux_loss_weight * stats["aux"],
+                dict(stats, ce=ce))
 
 
 # ------------------------------------------------------------- decoding
@@ -120,12 +128,12 @@ def cache_len_for(cfg: ModelConfig, seq_len: int) -> int:
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int):
-    return tf_mod.init_stacked_cache(
+    return tf_mod.init_layers_cache(
         cfg, batch, cache_len_for(cfg, seq_len), jnp.dtype(cfg.dtype))
 
 
 def cache_specs(cfg: ModelConfig, batch: int, seq_len: int):
-    return tf_mod.stacked_cache_specs(
+    return tf_mod.layers_cache_specs(
         cfg, batch, cache_len_for(cfg, seq_len), jnp.dtype(cfg.dtype))
 
 
@@ -136,9 +144,9 @@ def cache_axes(cfg: ModelConfig):
 def decode_step(cfg: ModelConfig, params, cache, tokens, pos):
     """One decode step. tokens: (B, 1) int32; pos: scalar absolute position.
     Returns (logits (B, 1, V), new_cache)."""
-    x = params["embed"][tokens] * (cfg.d_model ** 0.5)
+    x = embed_tokens(cfg, params, tokens)
     x = logical_shard(x, "batch", None, "embed")
-    x, new_cache = tf_mod.stack_decode(cfg, params["blocks"], cache, x, pos)
+    x, new_cache = tf_mod.layers_decode(cfg, params, cache, x, pos)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head(cfg, params, x)
     return logits, new_cache

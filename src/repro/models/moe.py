@@ -1,4 +1,18 @@
-"""Top-k MoE with grouped, gather/scatter-based capacity dispatch.
+"""Top-k MoE: the dropless expert layer (``dropless_fwd``, where
+``cfg.dropless``) and the capacity dispatch (``moe_fwd``).
+
+Dropless: the router scores every expert; this chip keeps the
+assignments to the experts it holds (one share of ``cfg.expert_shards``;
+on one chip the first), sorts them by expert, and runs each SwiGLU matrix
+as one grouped matmul over the sorted rows (``kernels.ops.grouped_matmul``),
+so every assignment is computed whatever the imbalance. Outputs, weighted
+by their gates, go back to their tokens; shared experts run on every
+token. What the experts held elsewhere add is not computed here: on one
+chip the layer runs without the exchange. Named scopes inside ``moe``:
+``router``, ``dispatch``, ``experts`` (the grouped contractions),
+``combine``, ``shared_expert``.
+
+Capacity dispatch: top-k MoE with grouped, gather/scatter-based slots.
 
 Tokens are reshaped to (G, T/G, D) where G tracks the data-parallel shard
 count; routing + capacity ranking happen per group (local under SPMD), and
@@ -12,21 +26,207 @@ Includes the Switch-style auxiliary load-balance loss.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import ParamDef
+from repro.kernels.ops import grouped_matmul
+from repro.models.layers import ParamDef, mlp_defs, mlp_fwd
 from repro.parallel.sharding import current_rules, logical_shard
+
+TOKEN_BLOCK = 8192       # tokens the dropless layer sorts at a time
+
+# how a stack's MoE statistics combine over layers
+STAT_MERGE = {"aux": jnp.add, "moe_rows_here": jnp.add,
+              "moe_load_max": jnp.maximum}
+
+
+def zero_stats(cfg) -> dict:
+    """MoE statistics before any layer: the aux loss, plus the dropless
+    layer's counters where ``cfg`` (None: no MoE layer) has that layer."""
+    out = {"aux": jnp.zeros((), jnp.float32)}
+    if cfg is not None and cfg.dropless:
+        out["moe_rows_here"] = jnp.zeros((), jnp.int32)
+        out["moe_load_max"] = jnp.zeros((), jnp.float32)
+    return out
 
 
 def moe_defs(cfg) -> dict:
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
-    return {
+    """Router over all ``num_experts``; SwiGLU weights of the experts held
+    here (all of them unless the config gives this chip a share)."""
+    D, F, E, Eh = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.held_experts
+    defs = {
         "router": ParamDef((D, E), ("embed", "experts")),
-        "w_gate": ParamDef((E, D, F), ("experts", "expert_embed", "mlp")),
-        "w_up": ParamDef((E, D, F), ("experts", "expert_embed", "mlp")),
-        "w_down": ParamDef((E, F, D), ("experts", "mlp", "expert_embed")),
+        "w_gate": ParamDef((Eh, D, F), ("experts", "expert_embed", "mlp")),
+        "w_up": ParamDef((Eh, D, F), ("experts", "expert_embed", "mlp")),
+        "w_down": ParamDef((Eh, F, D), ("experts", "mlp", "expert_embed")),
     }
+    if cfg.router_score == "sigmoid":
+        # chooses experts and takes no gradient (DeepSeek-V3 updates it by
+        # a rule outside the gradient, which this trainer does not run)
+        defs["router_bias"] = ParamDef((E,), (None,), init="zeros")
+    if cfg.shared_experts:
+        defs["shared"] = mlp_defs(D, cfg.shared_experts * F)
+    return defs
+
+
+def even_share_offset(biased, lo: int, held: int, k: int):
+    """The offset that, added to experts ``[lo, lo + held)`` of the
+    biased scores (T, E), gives them an even share, T k held / E, of the
+    top-k choices. Token t's j-th best held expert enters its top k once
+    the offset passes d[t, j] = (its (k - j + 1)-th best other expert) -
+    (its j-th best held one), which grows with j; so N = T k held / E
+    choices fall here for any offset between the N-th and the (N+1)-th
+    smallest d, and the midpoint is taken."""
+    T, E = biased.shape
+    if E - held < k:
+        raise ValueError("an even share needs k experts held elsewhere")
+    j = min(k, held)
+    mine = jax.lax.top_k(biased[:, lo:lo + held], j)[0]
+    rest = jnp.concatenate([biased[:, :lo], biased[:, lo + held:]], 1)
+    rest = jax.lax.top_k(rest, k)[0][:, k - j:][:, ::-1]
+    d = jnp.sort((rest - mine).reshape(-1))
+    d = jnp.concatenate([d[:1] - 1, d, d[-1:] + 1])
+    n = T * k * held // E
+    return (d[n] + d[n + 1]) / 2
+
+
+def route(cfg, p, x, shard: int = 0):
+    """Router over all experts. x (T, D) -> (expert ids (T, K), gates
+    (T, K) f32, aux loss). Scores in float32: softmax, or sigmoid with
+    the top k chosen by score + correction bias; the chosen scores are
+    normalised to sum to ``cfg.route_scale``. Where ``cfg.even_share``,
+    the held experts (share ``shard``) choose with their bias offset by
+    ``even_share_offset`` on this batch."""
+    E, K = cfg.num_experts, cfg.experts_per_token
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        biased = jax.lax.stop_gradient(
+            scores + p["router_bias"].astype(jnp.float32))
+        if cfg.even_share and cfg.expert_shards > 1:
+            Eh = cfg.held_experts
+            lo = shard * Eh
+            biased = biased.at[:, lo:lo + Eh].add(
+                even_share_offset(biased, lo, Eh, K))
+        _, e_idx = jax.lax.top_k(biased, K)
+        gates = jnp.take_along_axis(scores, e_idx, axis=-1)
+        probs = scores / jnp.sum(scores, -1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, e_idx = jax.lax.top_k(probs, K)
+    gates = cfg.route_scale * gates / jnp.maximum(
+        gates.sum(-1, keepdims=True), 1e-9)
+    density = jnp.mean(jax.nn.one_hot(e_idx[:, 0], E, dtype=jnp.float32), 0)
+    aux = E * jnp.sum(density * jnp.mean(probs, 0))
+    return e_idx, gates, aux
+
+
+# Dispatch and combine move rows by a permutation of the T x K
+# assignments and its inverse. Each one's cotangent goes back by a gather
+# with the other, not by a scatter-add.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inv, k: int):
+    """Row a of the result is ``x[order[a] // k]``: x (T, D) -> (T k, D)."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, k):
+    return x[order // k], inv
+
+
+def _dispatch_bwd(k, inv, g):
+    gt = g[inv].reshape(-1, k, g.shape[-1])
+    return jnp.sum(gt.astype(jnp.float32), 1).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv``."""
+    return x[idx]
+
+
+def _permute_fwd(x, idx, inv):
+    return x[idx], inv
+
+
+def _permute_bwd(inv, g):
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _held_block(cfg, p, shard: int, blk):
+    """One token block through the experts of share ``shard``: xt (Tb,
+    D), e_idx and gates (Tb, K) -> (y (Tb, D), rows per held expert
+    (Eh,))."""
+    xt, e_idx, gates = blk
+    Tb, D = xt.shape
+    K, Eh = cfg.experts_per_token, cfg.held_experts
+    with jax.named_scope("dispatch"):
+        local = e_idx.reshape(-1) - shard * Eh                  # (Tb*K,)
+        held = (local >= 0) & (local < Eh)
+        group = jnp.where(held, local, Eh)     # elsewhere: a last group
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(Tb * K, dtype=jnp.int32))
+        sizes = jnp.sum(jax.nn.one_hot(group, Eh + 1, dtype=jnp.int32), 0)
+        xs = _dispatch(xt, order, inv, K)      # rows by expert
+    with jax.named_scope("experts"):
+        # the last group's rows (held elsewhere) are not computed and
+        # come out as zeros
+        g = grouped_matmul(xs, p["w_gate"], sizes)
+        u = grouped_matmul(xs, p["w_up"], sizes)
+    h = jax.nn.silu(g) * u
+    with jax.named_scope("experts"):
+        ys = grouped_matmul(h, p["w_down"], sizes)
+    with jax.named_scope("combine"):
+        w = jnp.where(held, gates.reshape(-1), 0.0).astype(ys.dtype)
+        yk = _permute(ys, inv, order) * w[:, None]
+        y = jnp.sum(yk.reshape(Tb, K, D).astype(jnp.float32), 1)
+    return y.astype(xt.dtype), sizes[:Eh]
+
+
+def dropless_fwd(cfg, p, x, shard: int = 0):
+    """x: (B, S, D) -> (y, MoE statistics: ``aux``, ``moe_rows_here``
+    (assignments computed here), ``moe_load_max`` (the fullest held
+    expert's rows over the held experts' mean)). ``p`` holds the experts
+    ``[shard * held_experts, (shard + 1) * held_experts)``; in an
+    expert-parallel run the index is the chip's place on the expert
+    axis.
+
+    The held experts take the tokens ``TOKEN_BLOCK`` at a time, each
+    block sorted and computed on its own and recomputed in the backward,
+    so the sorted rows (a block's tokens x K, since every assignment may
+    fall here) are held for one block at a time."""
+    B, S, D = x.shape
+    T, K, Eh = B * S, cfg.experts_per_token, cfg.held_experts
+    xt = x.reshape(T, D)
+    with jax.named_scope("router"):
+        e_idx, gates, aux = route(cfg, p, xt, shard)
+    tb = TOKEN_BLOCK if T % TOKEN_BLOCK == 0 else T
+    blocks = (xt.reshape(-1, tb, D), e_idx.reshape(-1, tb, K),
+              gates.reshape(-1, tb, K))
+    y, sizes = jax.lax.map(
+        jax.checkpoint(functools.partial(_held_block, cfg, p, shard)),
+        blocks)
+    y = y.reshape(T, D)
+    if cfg.shared_experts:
+        with jax.named_scope("shared_expert"):
+            y = y + mlp_fwd(p["shared"], x).reshape(T, D)
+    sizes = jnp.sum(sizes, 0)
+    rows = jnp.sum(sizes)
+    load = jnp.max(sizes) * Eh / jnp.maximum(rows, 1)
+    stats = {"aux": aux, "moe_rows_here": rows,
+             "moe_load_max": load.astype(jnp.float32)}
+    return y.reshape(B, S, D), stats
 
 
 def capacity(cfg, tokens_per_group: int) -> int:

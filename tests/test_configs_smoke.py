@@ -88,6 +88,14 @@ def test_full_config_matches_assignment(arch):
                               num_kv_heads=8, d_ff=16384, vocab_size=92553),
         "minitron-4b": dict(num_layers=32, d_model=3072, num_heads=24,
                             num_kv_heads=8, d_ff=9216, vocab_size=256000),
+        "moonlight-16b-a3b": dict(num_layers=27, d_model=2048, num_heads=16,
+                                  num_kv_heads=16, d_ff=1408,
+                                  vocab_size=163840, num_experts=64,
+                                  experts_per_token=6, kv_lora_rank=512,
+                                  qk_nope_head_dim=128, qk_rope_head_dim=64,
+                                  v_head_dim=128, shared_experts=2,
+                                  first_dense_layers=1, dense_ff=11264,
+                                  route_scale=2.446, router_score="sigmoid"),
     }[arch]
     for k, v in spec.items():
         assert getattr(cfg, k) == v, (arch, k, getattr(cfg, k), v)

@@ -159,7 +159,7 @@ def test_attn_impl_auto_follows_backend(monkeypatch, impl, backend, seq,
     import jax
     from repro.kernels.ops import resolve_attn_impl
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    assert resolve_attn_impl(impl, seq, window) == expect
+    assert resolve_attn_impl(impl, seq, window, 128) == expect
 
 
 @pytest.mark.parametrize("axes,expect", [

@@ -116,6 +116,52 @@ def test_splash_attention_trains_on_v5e(one_chip):
     assert scan.memory_analysis().temp_size_in_bytes > 2**30
 
 
+def test_latent_attention_kernel_trains_on_v5e(one_chip):
+    """Forward and backward of the kernel at the Moonlight cell's latent
+    attention (batch 4, S 8192, 16 heads, q/k 192 and v 128), with the
+    block sizes its row of the table gives."""
+    from repro.kernels.ops import splash_block_sizes
+    B, S, H = 4, 8192, 16
+    assert splash_block_sizes(S, 192) is not None
+    qk = _spec((B, S, H, 192), jnp.bfloat16, one_chip)
+    v = _spec((B, S, H, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        o = gqa_splash_attention(q, k, v, interpret=False)
+        assert o.shape == (B, S, H, 128)
+        return jnp.sum(o.astype(jnp.float32))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 2
+
+
+def test_held_experts_compile_for_v5e(one_chip, monkeypatch):
+    """The dropless layer's block of 8192 tokens at Moonlight's widths (8
+    of 64 experts held, top 6), forward and backward, through the
+    compiled grouped matmul kernels: the sorted rows of one block, not of
+    the whole batch, are its temporaries."""
+    from repro.configs import get_config
+    from repro.models import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("moonlight-16b-a3b").replace(expert_shards=8)
+    Tb, D, F, K, Eh = moe.TOKEN_BLOCK, cfg.d_model, cfg.d_ff, 6, 8
+    p = {n: _spec(s, jnp.bfloat16, one_chip) for n, s in
+         (("w_gate", (Eh, D, F)), ("w_up", (Eh, D, F)), ("w_down", (Eh, F, D)))}
+    blk = (_spec((Tb, D), jnp.bfloat16, one_chip),
+           _spec((Tb, K), jnp.int32, one_chip),
+           _spec((Tb, K), jnp.float32, one_chip))
+
+    def loss(p, xt, e_idx, gates):
+        y, _ = moe._held_block(cfg, p, 0, (xt, e_idx, gates))
+        return jnp.sum(y.astype(jnp.float32))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, *blk).compile()
+    # gmm forward for gate and up (the loss is linear in the down
+    # matmul's output), and gmm + tgmm backward for each of the three
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 8
+    # (Tb x K, D) sorted rows in bf16 are 0.2 GB a copy
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
+
+
 def test_ssd_scan_compiles_for_v5e(one_chip):
     """At mamba2-130m's heads (24 x 64, state 128, chunk 128)."""
     cfg = get_config("mamba2-130m")
